@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.optimistic_lookup.kernel import optimistic_lookup
+from repro.kernels.optimistic_lookup.kernel import TILE, optimistic_lookup
 from repro.kernels.tide_attention.kernel import tide_attention
 from repro.kernels.tide_attention.ref import tide_attention_ref
 
@@ -25,12 +25,12 @@ def run(csv=print) -> None:
     keys = np.unique(rng.integers(0, 2**32, N, dtype=np.uint32))
     queries = jnp.asarray(rng.integers(0, 2**32, Q, dtype=np.uint32))
     kj = jnp.asarray(keys)
-    for w in (128, 256, 512, 1024, 2048):
+    for w in (1024, 2048, 4096):         # windows are whole 1024-key tiles
         idx, found, iters = jax.block_until_ready(
-            optimistic_lookup(queries, kj, window=w, interpret=True))
+            optimistic_lookup(queries, kj, window=w))
         it = np.asarray(iters)
         resolved = (np.asarray(idx) >= 0).mean()
-        bytes_per_lookup = int(it.mean() * w * 4)
+        bytes_per_lookup = int(it.mean() * -(-w // TILE) * TILE * 4)
         csv(f"kernel.optimistic.w{w},{it.mean():.3f},"
             f"iters/lookup bytes_staged={bytes_per_lookup} "
             f"resolved={resolved:.3f}")

@@ -7,6 +7,7 @@ reproduction targets — see DESIGN.md §9.
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 
@@ -19,6 +20,10 @@ def main() -> None:
                          "system,validator,kernels,roofline")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+
+    from repro import compile_cache
+    compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     from . import (faults, index_formats, kernel_bench, kv_exists,
                    kv_throughput, kv_write, overload, recovery, relocation,

@@ -687,9 +687,20 @@ class LargeTable:
             dtype=">u4").astype(np.uint32)
         if use_kernel and len(queries) >= _KERNEL_MIN_QUERIES:
             from repro.kernels.optimistic_lookup.ops import lookup_indices_batch
-            idx, found = lookup_indices_batch(q32, u32,
-                                              window=ks.cfg.window_entries)
-            self.metrics.add(batched_kernel_lookups=len(queries))
+            # Each query interpolates inside its own cell's slice of the
+            # concatenation: for h = the key's u32 prefix, the cell is
+            # (h·n_cells) >> 32 and (h·n_cells) mod 2³² is the key's
+            # fractional position inside that cell's key range.
+            sizes = [len(p[1]) for p in parts]
+            per_q = [len(p[0]) for p in parts]
+            base = np.repeat(np.cumsum([0] + sizes[:-1]), per_q)
+            count = np.repeat(sizes, per_q)
+            frac = (q32.astype(np.uint64) * ks.cfg.n_cells).astype(np.uint32)
+            idx, found, unresolved = lookup_indices_batch(
+                q32, u32, segments=(base, count, frac),
+                window=ks.cfg.window_entries)
+            self.metrics.add(batched_kernel_lookups=len(queries),
+                             kernel_unresolved=unresolved)
         else:
             idx = np.searchsorted(u32, q32, side="left").astype(np.int64)
             safe = np.minimum(idx, total - 1)

@@ -99,6 +99,7 @@ class Metrics:
     batched_append_runs: int = 0       # coalesced pwrite runs (append_many)
     batched_blob_reads: int = 0        # whole-cell index reads (multi_get)
     batched_kernel_lookups: int = 0    # queries resolved via Pallas kernel
+    kernel_unresolved: int = 0         # ... of which the kernel left to the host
     batched_read_keys: int = 0         # keys entering multi_get/multi_exists
     batched_read_runs: int = 0         # coalesced WAL pread runs issued
     batched_write_records: int = 0     # records entering append_many

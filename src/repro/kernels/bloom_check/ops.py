@@ -1,10 +1,8 @@
-"""jit'd wrappers for bloom_check.
+"""Host-facing entry of the fused Bloom probe.
 
-``might_contain`` is the raw device-array interface.  ``might_contain_batch``
-is the host-facing entry for one cell's bitset; ``probe_cells_batch`` is the
-fused ragged entry the storage engine's existence path uses — every touched
-cell's bit array packed into one buffer, every (key, cell) pair probed in
-ONE dispatch.  Both are numpy in / numpy out, with query-count and
+``probe_cells_batch`` is what the storage engine's existence path uses:
+every touched cell's bit array packed into one buffer, every (key, cell)
+pair probed in ONE dispatch.  Numpy in / numpy out, with query-count and
 bitset-word padding to power-of-two buckets so the jit cache stays small
 across cells of different sizes.
 
@@ -15,64 +13,21 @@ however many cells the batch touches.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import bloom_check, bloom_check_ragged
-from .ref import bloom_check_ragged_ref, bloom_check_ref
+from .kernel import bloom_check_ragged
 from ..padding import next_pow2
 
 ragged_dispatch_count = 0
 
-
-@functools.partial(jax.jit, static_argnames=("k", "nbits", "impl", "interpret"))
-def might_contain(h1, h2, bits, *, k: int = 7, nbits: int | None = None,
-                  impl: str = "pallas", interpret: bool = True):
-    if impl == "pallas":
-        return bloom_check(h1, h2, bits, k=k, nbits=nbits, interpret=interpret)
-    return bloom_check_ref(h1, h2, bits, k=k, nbits=nbits)
-
-
-def might_contain_batch(h1: np.ndarray, h2: np.ndarray, bits: np.ndarray,
-                        *, k: int = 7, nbits: int | None = None,
-                        impl: str = "pallas") -> np.ndarray:
-    """Batched membership test: h1/h2 (Q,) u32, bits (nwords,) u32 → (Q,) bool.
-
-    ``nbits`` is the filter's true modulus (it need not equal nwords·32 once
-    the word array is padded).  Padding queries probe slot 0 and are sliced
-    off; padded bitset words are never indexed because nbits stays fixed.
-    """
-    q = len(h1)
-    if q == 0:
-        return np.zeros(0, dtype=bool)
-    nbits = nbits if nbits is not None else bits.shape[0] * 32
-    qp = next_pow2(q)
-    if qp != q:
-        h1 = np.concatenate([h1, np.zeros(qp - q, np.uint32)])
-        h2 = np.concatenate([h2, np.ones(qp - q, np.uint32)])
-    wp = next_pow2(bits.shape[0])
-    if wp != bits.shape[0]:
-        bits = np.concatenate([bits, np.zeros(wp - bits.shape[0], np.uint32)])
-    out = might_contain(jnp.asarray(h1), jnp.asarray(h2), jnp.asarray(bits),
-                        k=k, nbits=nbits, impl=impl)
-    return np.asarray(out)[:q]
-
-
-@functools.partial(jax.jit, static_argnames=("k", "impl", "interpret"))
-def probe_ragged(h1, h2, off, nbits, bits, *, k: int = 7,
-                 impl: str = "pallas", interpret: bool = True):
-    if impl == "pallas":
-        return bloom_check_ragged(h1, h2, off, nbits, bits, k=k,
-                                  interpret=interpret)
-    return bloom_check_ragged_ref(h1, h2, off, nbits, bits, k=k)
+_probe = jax.jit(bloom_check_ragged, static_argnames=("k",))
 
 
 def probe_cells_batch(h1: np.ndarray, h2: np.ndarray, off: np.ndarray,
-                      nbits: np.ndarray, bits: np.ndarray, *, k: int = 7,
-                      impl: str = "pallas") -> np.ndarray:
+                      nbits: np.ndarray, bits: np.ndarray, *,
+                      k: int = 7) -> np.ndarray:
     """Fused ragged membership: h1/h2 (Q,) u32, off (Q,) i32 word bases,
     nbits (Q,) u32 per-query moduli, bits (total_words,) u32 packed cells
     → (Q,) bool, in ONE kernel dispatch.
@@ -97,8 +52,7 @@ def probe_cells_batch(h1: np.ndarray, h2: np.ndarray, off: np.ndarray,
         bits = np.concatenate([bits, np.zeros(wp - bits.shape[0], np.uint32)])
     global ragged_dispatch_count
     ragged_dispatch_count += 1
-    out = probe_ragged(jnp.asarray(h1), jnp.asarray(h2),
-                       jnp.asarray(off, jnp.int32),
-                       jnp.asarray(nbits, jnp.uint32),
-                       jnp.asarray(bits), k=k, impl=impl)
+    out = _probe(jnp.asarray(h1), jnp.asarray(h2),
+                 jnp.asarray(off, jnp.int32), jnp.asarray(nbits, jnp.uint32),
+                 jnp.asarray(bits), k=k)
     return np.asarray(out)[:q]

@@ -1,22 +1,28 @@
 """optimistic_lookup — the paper's §4.2 interpolation search on TPU.
 
-Given a sorted array of uint32 keys resident in HBM (an on-device index,
-e.g. hash-addressed KV-cache lookup or a device-resident Large Table cell),
-each grid step resolves one query:
+The sorted u32 key column stays in HBM as (N/1024, 8, 128) tiles of 1024
+keys.  Each grid step resolves one query:
 
-1. estimate the key's fractional position:  est = key/2³² · N      (§4.2)
-2. stage a W-entry window around est into VMEM (the analogue of the 32 KB
-   SSD read — one VMEM tile costs the same regardless of W ≤ tile),
-3. test window bounds; if the key falls outside, shift the window toward
-   the right end and repeat — a *fixed* unrolled iteration budget keeps the
-   kernel branchless (masked updates), matching the paper's 1–3-round-trip
-   convergence for uniform keys,
-4. rank the key inside the final window with a vectorized compare-reduce.
+1. estimate the key's position by interpolation inside its segment:
+   est = base + frac·count/2³² (§4.2).  A segment is the slice of the
+   column that the key can lie in — one Large Table cell when the column
+   is a concatenation of cells — and frac is the key's fractional position
+   inside that segment's key range.  Without segments the whole column is
+   one segment of the real key count and frac is the key itself, so
+   padding past the real keys never skews the estimate,
+2. DMA a window of whole tiles around est from HBM into VMEM (the analogue
+   of the 32 KB SSD read),
+3. count the window's entries below and at the key; if the key lies
+   outside the window, step the window toward it and repeat, within a
+   fixed budget of ``max_iters`` windows,
+4. rank = window start + entries below the key: the searchsorted-left
+   insertion point over the whole column — or a place inside a run of
+   equal keys when that run crosses the window's start.
 
-Returns (index, found, iterations-used) per query.  ``found`` is False both
-for absent keys and (rare, non-uniform adversarial input) budget exhaustion
-— the host falls back to a full binary search, mirroring the engine's
-linear-probe → bisection fallback.
+Returns (index, found, windows-used) per query.  ``index`` is -1 where the
+budget ran out (rare, non-uniform adversarial input); the host resolves
+only those queries, mirroring the engine's linear-probe → bisection
+fallback.
 """
 from __future__ import annotations
 
@@ -27,75 +33,115 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import platform
 
-def _kernel(queries_ref, keys_ref, idx_ref, found_ref, iters_ref,
-            *, n_keys: int, window: int, max_iters: int):
+TILE = 1024                       # keys per (8, 128) i32 tile
+_SIGN = -2**31                    # u32 order == i32 order after x ^ _SIGN
+
+
+def _srl(x, s: int):
+    return jax.lax.shift_right_logical(x, jnp.int32(s))
+
+
+def _mul_hi(a, b):
+    """High word of the u32 product a·b (both as i32 bit patterns), within
+    2 of exact: the 64-bit product from 16-bit halves, minus the low×low
+    carry — the chip's scalar unit has no 64-bit multiply."""
+    ah, al = _srl(a, 16), a & 0xFFFF
+    bh, bl = _srl(b, 16), b & 0xFFFF
+    return ah * bh + _srl(ah * bl, 16) + _srl(al * bh, 16)
+
+
+def _kernel(n_ref, key_ref, base_ref, count_ref, frac_ref, keys_hbm,
+            idx_ref, found_ref, iters_ref, win, sem, red,
+            *, tiles: int, max_iters: int):
     qi = pl.program_id(0)
-    key = queries_ref[qi]
-    kf = key.astype(jnp.float32)
-    est = (kf * (1.0 / 4294967296.0) * n_keys).astype(jnp.int32)
+    n = n_ref[0]
+    key = key_ref[qi] ^ _SIGN
+    est = base_ref[qi] + _mul_hi(frac_ref[qi], count_ref[qi])
+    width = tiles * TILE
+    n_tiles = (n + TILE - 1) // TILE
+    max_t0 = jnp.maximum(n_tiles - tiles, 0)
+    # First window: the tile boundary nearest est - width/2, so est sits
+    # at least half a tile from either edge.
+    t0 = jnp.minimum(jnp.maximum(est - width // 2 + TILE // 2, 0) // TILE,
+                     max_t0)
 
-    max_start = max(n_keys - window, 0)
+    def cond(c):
+        used, _, done, _, _ = c
+        return (used < max_iters) & (done == 0)
 
-    def clamp(s):
-        return jnp.clip(s, 0, max_start)
-
-    start = clamp(est - window // 2)
-    done = jnp.bool_(False)
-    found_idx = jnp.int32(0)
-    found = jnp.bool_(False)
-    used = jnp.int32(0)
-
-    for _ in range(max_iters):
-        w = keys_ref[pl.ds(start, window)]               # VMEM window stage
-        lo_ok = (start == 0) | (w[0] <= key)
-        hi_ok = (start + window >= n_keys) | (key <= w[window - 1])
+    def body(c):
+        used, t0, _, _, _ = c
+        copy = pltpu.make_async_copy(keys_hbm.at[pl.ds(t0, tiles)], win, sem)
+        copy.start()
+        copy.wait()
+        w = win[...]
+        red[0] = jnp.sum((w < key).astype(jnp.int32))
+        red[1] = jnp.sum((w <= key).astype(jnp.int32))
+        below, at_or_below = red[0], red[1]
+        lo_ok = (t0 == 0) | (at_or_below > 0)          # w[0] <= key
+        hi_ok = (t0 + tiles >= n_tiles) | (below < width)  # key <= w[-1]
         inside = lo_ok & hi_ok
-        # rank within window: count of entries < key (vector compare-reduce)
-        rank = jnp.sum((w < key).astype(jnp.int32))
-        hit = jnp.sum((w == key).astype(jnp.int32)) > 0
-        newly = inside & ~done
-        found_idx = jnp.where(newly, start + rank, found_idx)
-        found = jnp.where(newly, hit, found)
-        used = used + jnp.where(~done, 1, 0).astype(jnp.int32)
-        done = done | inside
-        # shift toward the key (paper: move window left/right; estimate is
-        # already near, so adjacent-window stepping converges in 1–3 hops)
-        start = jnp.where(done, start,
-                          clamp(jnp.where(lo_ok, start + window,
-                                          start - window)))
+        rank = t0 * TILE + below
+        hit = (at_or_below > below) & (rank < n)
+        step = jnp.where(lo_ok, t0 + tiles, t0 - tiles)
+        return (used + 1,
+                jnp.where(inside, t0, jnp.clip(step, 0, max_t0)),
+                inside.astype(jnp.int32), rank, hit.astype(jnp.int32))
 
-    idx_ref[qi] = jnp.where(done, found_idx, jnp.int32(-1))
-    found_ref[qi] = (found & done)
+    used, _, done, rank, hit = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), t0, jnp.int32(0), jnp.int32(0),
+                     jnp.int32(0)))
+    idx_ref[qi] = jnp.where(done == 1, rank, -1)
+    found_ref[qi] = hit * done
     iters_ref[qi] = used
 
 
-def optimistic_lookup(queries: jax.Array, keys: jax.Array, *,
-                      window: int = 512, max_iters: int = 4,
-                      interpret: bool = False):
-    """queries (Q,) u32; keys (N,) u32 sorted ascending.
-    → (idx (Q,) i32 [-1 if unresolved], found (Q,) bool, iters (Q,) i32)."""
-    Q = queries.shape[0]
-    N = keys.shape[0]
-    window = min(window, N)
-    kernel = functools.partial(_kernel, n_keys=N, window=window,
-                               max_iters=max_iters)
-    return pl.pallas_call(
+def _as_i32(x):
+    x = jnp.asarray(x)
+    if x.dtype == jnp.uint32:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def optimistic_lookup(queries: jax.Array, keys: jax.Array, n_keys=None,
+                      segments=None, *, window: int = 2048,
+                      max_iters: int = 4):
+    """queries (Q,) u32; keys (N,) u32 sorted ascending, of which the first
+    ``n_keys`` (an i32 scalar; default N) are real and the rest padding
+    that sorts last.  ``segments`` is ``(base, count, frac)``, three (Q,)
+    arrays: query q's key lies in ``keys[base:base+count]`` at fractional
+    position ``frac/2³²`` of that slice's key range.  ``window`` rounds up
+    to whole tiles of 1024 keys.
+
+    → (idx (Q,) i32 [-1 if unresolved], found (Q,) bool, iters (Q,) i32).
+    """
+    Q, N = queries.shape[0], keys.shape[0]
+    n = N if n_keys is None else n_keys
+    n = jnp.reshape(jnp.asarray(n, jnp.int32), (1,))
+    if segments is None:
+        segments = (jnp.zeros(Q, jnp.int32), jnp.broadcast_to(n, (Q,)),
+                    queries)
+    base, count, frac = (_as_i32(a) for a in segments)
+    n_pad = -(-N // TILE) * TILE
+    keys = jnp.pad(_as_i32(keys) ^ _SIGN, (0, n_pad - N),
+                   constant_values=2**31 - 1)
+    tiles = min(max(1, -(-window // TILE)), n_pad // TILE)
+    kernel = functools.partial(_kernel, tiles=tiles, max_iters=max_iters)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    idx, found, iters = pl.pallas_call(
         kernel,
-        grid=(Q,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),       # queries (scalars)
-            pl.BlockSpec(memory_space=pl.ANY),        # keys stay in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Q,), jnp.int32),
-            jax.ShapeDtypeStruct((Q,), jnp.bool_),
-            jax.ShapeDtypeStruct((Q,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries, keys)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,        # n, queries, base, count, frac
+            grid=(Q,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # column in HBM
+            out_specs=[smem, smem, smem],
+            scratch_shapes=[pltpu.VMEM((tiles, 8, 128), jnp.int32),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SMEM((2,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((Q,), jnp.int32)] * 3,
+        interpret=platform.interpret(),
+    )(n, _as_i32(queries), base, count, frac,
+      keys.reshape(n_pad // TILE, 8, 128))
+    return idx, found.astype(jnp.bool_), iters

@@ -1,55 +1,23 @@
-"""jit'd wrappers: resolve WAL positions for hash keys via the optimistic
-index, falling back to the oracle for unresolved (budget-exhausted) queries.
+"""Host-facing entry of the optimistic lookup, used by the storage engine's
+batched read pipeline (``TideDB.multi_get``): numpy in, numpy out.
 
-``lookup_indices`` / ``lookup_positions`` are the raw device interfaces.
-``lookup_indices_batch`` is the host-facing entry used by the storage
-engine's batched read pipeline (``TideDB.multi_get``): numpy in, numpy out,
-padding both axes to power-of-two buckets so repeated calls over cells of
-slightly different sizes reuse the same compiled kernel.
+Both axes pad to power-of-two buckets so repeated calls over cells of
+slightly different sizes reuse the same compiled kernel; the real key
+count travels as data, so padding never moves the kernel's estimate.  The
+host searches only the queries the kernel left unresolved.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .kernel import optimistic_lookup
-from .ref import optimistic_lookup_ref
 from ..padding import next_pow2
 
 _PAD_KEY = np.uint32(0xFFFFFFFF)
 
-
-@functools.partial(jax.jit,
-                   static_argnames=("window", "max_iters", "interpret"))
-def lookup_indices(queries, keys, *, window: int = 512,
-                   max_iters: int = 4, interpret: bool = True):
-    """queries (Q,) u32; keys (N,) u32 sorted.  Returns (idx (Q,) i32,
-    found (Q,) bool): idx is the rank of the first key equal to the query
-    (insertion point when absent), kernel-resolved with oracle fallback."""
-    idx, found, iters = optimistic_lookup(queries, keys, window=window,
-                                          max_iters=max_iters,
-                                          interpret=interpret)
-    unresolved = idx < 0
-    ref_idx, ref_found = optimistic_lookup_ref(queries, keys)
-    idx = jnp.where(unresolved, ref_idx, idx)
-    found = jnp.where(unresolved, ref_found, found)
-    return idx, found
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("window", "max_iters", "interpret"))
-def lookup_positions(queries, keys, positions, *, window: int = 512,
-                     max_iters: int = 4, interpret: bool = True):
-    """queries (Q,) u32; keys (N,) u32 sorted; positions (N,) — the WAL
-    offsets.  Returns (pos (Q,), found (Q,) bool)."""
-    idx, found = lookup_indices(queries, keys, window=window,
-                                max_iters=max_iters, interpret=interpret)
-    safe = jnp.clip(idx, 0, keys.shape[0] - 1)
-    return jnp.where(found, positions[safe], 0), found
-
+_lookup = jax.jit(optimistic_lookup, static_argnames=("window", "max_iters"))
 
 # Fixed per-call query width: every kernel invocation sees Q=_Q_CHUNK, so
 # the jit cache holds one entry per key-count bucket instead of one per
@@ -57,36 +25,52 @@ def lookup_positions(queries, keys, positions, *, window: int = 512,
 _Q_CHUNK = 256
 
 
-def lookup_indices_batch(queries: np.ndarray, keys: np.ndarray, *,
-                         window: int = 512,
-                         max_iters: int = 4) -> tuple[np.ndarray, np.ndarray]:
-    """Batched index resolution: queries (Q,) u32, keys (N,) u32 sorted →
-    (idx (Q,) i32, found (Q,) bool) as numpy.
+def _pad(a: np.ndarray, size: int, fill) -> np.ndarray:
+    return np.concatenate([a, np.full(size - len(a), fill, a.dtype)])
 
-    Queries run through the kernel in fixed-width chunks of ``_Q_CHUNK``
-    (zero-padded); keys are padded to the next power of two with 0xFFFFFFFF
-    sentinels (preserving sort order).  Hits landing in the key padding are
-    masked out, so callers never observe a sentinel match.
+
+def lookup_indices_batch(queries: np.ndarray, keys: np.ndarray, *,
+                         segments=None, window: int = 2048,
+                         max_iters: int = 4
+                         ) -> tuple[np.ndarray, np.ndarray, int]:
+    """Batched index resolution: queries (Q,) u32, keys (N,) u32 sorted →
+    (idx (Q,) i64, found (Q,) bool, unresolved), where ``unresolved`` counts
+    the queries the kernel left to the host's binary search.
+
+    ``segments`` is ``(base, count, frac)`` per query, as the kernel takes
+    it: the slice of ``keys`` the query's key can lie in and the key's
+    fractional position (u32) in that slice's key range.  None makes the
+    whole column one segment.  Queries run through the kernel in
+    fixed-width chunks of ``_Q_CHUNK``; keys pad to the next power of two
+    with 0xFFFFFFFF sentinels (preserving sort order), and hits never land
+    in the padding.
     """
     q, n = len(queries), len(keys)
     if q == 0 or n == 0:
-        return (np.zeros(q, np.int32), np.zeros(q, dtype=bool))
+        return np.zeros(q, np.int64), np.zeros(q, dtype=bool), 0
+    queries = np.asarray(queries, np.uint32)
     # Floor the key bucket at 4096 so workloads whose touched-cell total
     # hovers around a power-of-two boundary don't recompile every few calls.
-    np_ = max(4096, next_pow2(n))
-    if np_ != n:
-        keys = np.concatenate([keys, np.full(np_ - n, _PAD_KEY, np.uint32)])
-    keys_j = jnp.asarray(keys)
+    keys_j = jnp.asarray(_pad(keys, max(4096, next_pow2(n)), _PAD_KEY))
+    n_j = jnp.int32(n)
+    qp = -(-q // _Q_CHUNK) * _Q_CHUNK
+    cols = [_pad(queries, qp, 0)]
+    if segments is not None:
+        cols += [_pad(np.asarray(a, dt), qp, 0) for a, dt in
+                 zip(segments, (np.int32, np.int32, np.uint32))]
     idx_parts, found_parts = [], []
-    for off in range(0, q, _Q_CHUNK):
-        chunk = queries[off:off + _Q_CHUNK]
-        if len(chunk) < _Q_CHUNK:
-            chunk = np.concatenate(
-                [chunk, np.zeros(_Q_CHUNK - len(chunk), np.uint32)])
-        idx, found = lookup_indices(jnp.asarray(chunk), keys_j,
-                                    window=window, max_iters=max_iters)
+    for off in range(0, qp, _Q_CHUNK):
+        qc, *seg = (jnp.asarray(c[off:off + _Q_CHUNK]) for c in cols)
+        idx, found, _ = _lookup(qc, keys_j, n_j, tuple(seg) or None,
+                                window=window, max_iters=max_iters)
         idx_parts.append(np.asarray(idx))
         found_parts.append(np.asarray(found))
-    idx = np.concatenate(idx_parts)[:q]
-    found = np.concatenate(found_parts)[:q] & (idx < n)
-    return idx.astype(np.int32), found
+    idx = np.concatenate(idx_parts)[:q].astype(np.int64)
+    found = np.concatenate(found_parts)[:q]
+    miss = np.flatnonzero(idx < 0)
+    if miss.size:
+        sub = queries[miss]
+        at = np.searchsorted(keys, sub, side="left")
+        idx[miss] = at
+        found[miss] = (at < n) & (keys[np.minimum(at, n - 1)] == sub)
+    return idx, found, int(miss.size)

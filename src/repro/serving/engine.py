@@ -189,7 +189,7 @@ class KvBatchServer:
         # one attribute check per idle tick.
         self.auto_recover = auto_recover
         self.recover_interval_s = recover_interval_s
-        self._last_recover_probe = 0.0
+        self._last_recover_probe: Optional[float] = None   # never probed
         self.auto_recover_probes = 0    # idle-tick probes attempted
         self.auto_recoveries = 0        # ... that brought the engine back
 
@@ -347,7 +347,8 @@ class KvBatchServer:
         if getattr(self.db, "health", "ok") != "degraded":
             return
         now = time.monotonic()
-        if now - self._last_recover_probe < self.recover_interval_s:
+        if (self._last_recover_probe is not None and
+                now - self._last_recover_probe < self.recover_interval_s):
             return
         self._last_recover_probe = now
         self.auto_recover_probes += 1

@@ -7,18 +7,27 @@ import pytest
 
 from hypothesis_compat import HealthCheck, given, settings, st
 
-from repro.kernels.bloom_check.kernel import bloom_check, bloom_check_ragged
+from repro.kernels.bloom_check.kernel import bloom_check_ragged
 from repro.kernels.bloom_check.ref import (bloom_add_ref,
                                            bloom_check_ragged_ref,
                                            bloom_check_ref)
 from repro.kernels.optimistic_lookup.kernel import optimistic_lookup
-from repro.kernels.optimistic_lookup.ops import lookup_positions
+from repro.kernels.optimistic_lookup.ops import lookup_indices_batch
 from repro.kernels.optimistic_lookup.ref import optimistic_lookup_ref
 from repro.kernels.tide_attention.kernel import tide_attention
 from repro.kernels.tide_attention.ref import tide_attention_ref
 
 SETTINGS = settings(max_examples=15, deadline=None,
                     suppress_health_check=[HealthCheck.too_slow])
+
+
+def bloom_check(h1, h2, bits, *, k=7, nbits=None):
+    """One filter probed through the ragged kernel: every query on one
+    cell at word 0."""
+    q = h1.shape[0]
+    nbits = nbits if nbits is not None else bits.shape[0] * 32
+    return bloom_check_ragged(h1, h2, jnp.zeros(q, jnp.int32),
+                              jnp.full(q, nbits, jnp.uint32), bits, k=k)
 
 
 def _mk_arena(key, B, NB, blk, KH, dk, dv, dtype):
@@ -120,8 +129,7 @@ class TestOptimisticLookup:
             rng.choice(keys, 64),
             rng.integers(0, 2**32, 64, dtype=np.uint32)]).astype(np.uint32)
         kj, qj = jnp.asarray(keys), jnp.asarray(queries)
-        idx, found, iters = optimistic_lookup(qj, kj, window=window,
-                                              interpret=True)
+        idx, found, iters = optimistic_lookup(qj, kj, window=window)
         ridx, rfound = optimistic_lookup_ref(qj, kj)
         resolved = np.asarray(idx) >= 0
         assert resolved.mean() > 0.99     # uniform keys: resolves in budget
@@ -138,19 +146,16 @@ class TestOptimisticLookup:
         # adversarial: clustered keys break the uniformity assumption
         keys = np.unique(np.concatenate([keys, np.arange(
             2**31, 2**31 + 4096, dtype=np.uint32)]))
-        queries = jnp.asarray(np.concatenate([
-            keys[:64], rng.integers(0, 2**32, 64, dtype=np.uint32)
-        ]).astype(np.uint32))
-        kj = jnp.asarray(keys)
-        pos = jnp.arange(len(keys), dtype=jnp.uint32) * 40
-        got, found = lookup_positions(queries, kj, pos, window=128,
-                                      max_iters=2)
-        ridx, rfound = optimistic_lookup_ref(queries, kj)
-        exp = np.where(np.asarray(rfound),
-                       np.asarray(pos)[np.clip(np.asarray(ridx), 0,
-                                               len(keys) - 1)], 0)
-        np.testing.assert_array_equal(np.asarray(got), exp)
-        np.testing.assert_array_equal(np.asarray(found), np.asarray(rfound))
+        queries = np.concatenate([
+            keys[:64], np.arange(2**31, 2**31 + 64, dtype=np.uint32),
+            rng.integers(0, 2**32, 64, dtype=np.uint32)]).astype(np.uint32)
+        idx, found, unresolved = lookup_indices_batch(
+            queries, keys, window=128, max_iters=2)
+        assert unresolved > 0                 # the host search ran
+        ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
+                                             jnp.asarray(keys))
+        np.testing.assert_array_equal(found, np.asarray(rfound))
+        np.testing.assert_array_equal(idx[found], np.asarray(ridx)[found])
 
     @given(seed=st.integers(0, 2**31 - 1), n=st.integers(10, 3000),
            window=st.sampled_from([128, 512]))
@@ -158,19 +163,89 @@ class TestOptimisticLookup:
     def test_property(self, seed, n, window):
         rng = np.random.default_rng(seed)
         keys = np.unique(rng.integers(0, 2**32, n, dtype=np.uint32))
-        queries = jnp.asarray(np.concatenate([
+        queries = np.concatenate([
             rng.choice(keys, 16), rng.integers(0, 2**32, 16,
                                                dtype=np.uint32)
-        ]).astype(np.uint32))
-        pos = jnp.arange(len(keys), dtype=jnp.uint32) + 7
-        got, found = lookup_positions(queries, jnp.asarray(keys), pos,
-                                      window=window)
-        ridx, rfound = optimistic_lookup_ref(queries, jnp.asarray(keys))
-        np.testing.assert_array_equal(np.asarray(found), np.asarray(rfound))
+        ]).astype(np.uint32)
+        pos = np.arange(len(keys), dtype=np.uint32) + 7
+        idx, found, _ = lookup_indices_batch(queries, keys, window=window)
+        got = np.where(found, pos[np.clip(idx, 0, len(keys) - 1)], 0)
+        ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
+                                             jnp.asarray(keys))
+        np.testing.assert_array_equal(found, np.asarray(rfound))
         exp = np.where(np.asarray(rfound),
-                       np.asarray(pos)[np.clip(np.asarray(ridx), 0,
-                                               len(keys) - 1)], 0)
-        np.testing.assert_array_equal(np.asarray(got), exp)
+                       pos[np.clip(np.asarray(ridx), 0, len(keys) - 1)], 0)
+        np.testing.assert_array_equal(got, exp)
+
+
+    def test_resolves_at_real_count_behind_padding(self):
+        """5,000 real keys padded to 8,192: the kernel interpolates over the
+        real count, so padding cannot skew its estimate."""
+        rng = np.random.default_rng(5000)
+        keys = np.unique(rng.integers(0, 2**32, 5000, dtype=np.uint32))
+        n = len(keys)
+        padded = np.concatenate(
+            [keys, np.full(8192 - n, 0xFFFFFFFF, np.uint32)])
+        queries = rng.choice(keys, 256)
+        idx, found, _ = optimistic_lookup(
+            jnp.asarray(queries), jnp.asarray(padded), n, window=512)
+        idx, found = np.asarray(idx), np.asarray(found)
+        resolved = idx >= 0
+        assert resolved.mean() >= 0.99
+        ridx, _ = optimistic_lookup_ref(jnp.asarray(queries),
+                                        jnp.asarray(keys))
+        np.testing.assert_array_equal(idx[resolved],
+                                      np.asarray(ridx)[resolved])
+        assert found[resolved].all()
+        # The host entry pads the same column itself.
+        _, bfound, unresolved = lookup_indices_batch(queries, keys,
+                                                     window=512)
+        assert unresolved <= 0.01 * len(queries) and bfound.all()
+
+    def test_segments_resolve_a_gapped_concatenation(self):
+        """The store concatenates only the cells a batch touches.  With
+        per-cell segments each query interpolates inside its own cell, so
+        gaps between the touched cells cannot skew the estimate."""
+        rng = np.random.default_rng(64)
+        n_cells = 64
+        keys = np.unique(rng.integers(0, 2**32, 60000, dtype=np.uint32))
+        cell = (keys.astype(np.uint64) * n_cells) >> 32
+        touched = np.r_[0:32, 63]              # a gap of 31 cells
+        kept = keys[np.isin(cell, touched)]
+        kcell = (kept.astype(np.uint64) * n_cells) >> 32
+        starts = np.searchsorted(kcell, np.arange(n_cells))
+        ends = np.searchsorted(kcell, np.arange(n_cells), side="right")
+        queries = np.concatenate([rng.choice(kept, 240), rng.integers(
+            0, 2**31, 16, dtype=np.uint32)])   # absent keys in cells 0-31
+        qcell = (queries.astype(np.uint64) * n_cells) >> 32
+        base = starts[qcell]
+        frac = (queries.astype(np.uint64) * n_cells).astype(np.uint32)
+        idx, found, unresolved = lookup_indices_batch(
+            queries, kept, segments=(base, ends[qcell] - base, frac),
+            window=512)
+        assert unresolved <= 0.01 * len(queries)
+        ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
+                                             jnp.asarray(kept))
+        np.testing.assert_array_equal(found, np.asarray(rfound))
+        np.testing.assert_array_equal(idx, np.asarray(ridx))
+
+    def test_multi_tile_window_matches_ref(self):
+        """Windows of several tiles over a column that is not a whole
+        number of tiles, with the window clamped at both ends."""
+        rng = np.random.default_rng(3)
+        keys = np.unique(rng.integers(0, 2**32, 9000, dtype=np.uint32))
+        queries = np.concatenate([
+            keys[:8], keys[-8:], rng.choice(keys, 100),
+            rng.integers(0, 2**32, 12, dtype=np.uint32),
+            np.array([0, 0xFFFFFFFF], np.uint32)]).astype(np.uint32)
+        idx, found, iters = optimistic_lookup(
+            jnp.asarray(queries), jnp.asarray(keys), window=3000)
+        idx, found = np.asarray(idx), np.asarray(found)
+        assert (idx >= 0).all() and int(np.asarray(iters).max()) <= 2
+        ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
+                                             jnp.asarray(keys))
+        np.testing.assert_array_equal(idx, np.asarray(ridx))
+        np.testing.assert_array_equal(found, np.asarray(rfound))
 
 
 class TestBloomCheck:
@@ -186,7 +261,7 @@ class TestBloomCheck:
             rng.integers(0, 2**32, 200, dtype=np.uint32))])
         h2q = jnp.concatenate([h2a, jnp.asarray(
             rng.integers(0, 2**32, 200, dtype=np.uint32) | 1)])
-        out = bloom_check(h1q, h2q, bits, k=k, interpret=True)
+        out = bloom_check(h1q, h2q, bits, k=k)
         ref = bloom_check_ref(h1q, h2q, bits, k=k)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
         assert bool(jnp.all(out[:nadd]))          # no false negatives
@@ -200,7 +275,7 @@ class TestBloomCheck:
         h1 = jnp.asarray(rng.integers(0, 2**32, 30, dtype=np.uint32))
         h2 = jnp.asarray(rng.integers(0, 2**32, 30, dtype=np.uint32) | 1)
         bits = bloom_add_ref(h1, h2, bits)
-        out = bloom_check(h1, h2, bits, interpret=True)
+        out = bloom_check(h1, h2, bits)
         assert bool(jnp.all(out))
 
 
@@ -248,7 +323,7 @@ class TestBloomCheckRagged:
         h1, h2, off, nb, packed, bounds = self._ragged_inputs(cells, 25, 7)
         out = bloom_check_ragged(jnp.asarray(h1), jnp.asarray(h2),
                                  jnp.asarray(off), jnp.asarray(nb),
-                                 jnp.asarray(packed), interpret=True)
+                                 jnp.asarray(packed))
         ref = bloom_check_ragged_ref(jnp.asarray(h1), jnp.asarray(h2),
                                      jnp.asarray(off), jnp.asarray(nb),
                                      jnp.asarray(packed))
@@ -258,8 +333,7 @@ class TestBloomCheckRagged:
             q = nadd + n_miss
             flat = bloom_check(jnp.asarray(h1[pos:pos + q]),
                                jnp.asarray(h2[pos:pos + q]),
-                               jnp.asarray(bits), nbits=nbits,
-                               interpret=True)
+                               jnp.asarray(bits), nbits=nbits)
             np.testing.assert_array_equal(np.asarray(out[pos:pos + q]),
                                           np.asarray(flat))
             assert bool(np.all(np.asarray(out[pos:pos + nadd])))
@@ -276,7 +350,7 @@ class TestBloomCheckRagged:
         h1, h2, off, nb, packed, bounds = self._ragged_inputs(cells, 9, seed)
         out = np.asarray(bloom_check_ragged(
             jnp.asarray(h1), jnp.asarray(h2), jnp.asarray(off),
-            jnp.asarray(nb), jnp.asarray(packed), interpret=True))
+            jnp.asarray(nb), jnp.asarray(packed)))
         pos = 0
         for (bits, _, _, nbits), (nadd, n_miss) in zip(cells, bounds):
             q = nadd + n_miss
@@ -285,6 +359,28 @@ class TestBloomCheckRagged:
                                    jnp.asarray(bits), nbits=nbits)
             np.testing.assert_array_equal(out[pos:pos + q], np.asarray(flat))
             pos += q
+
+
+    def test_blocked_grid_matches_ref(self):
+        """More queries than one SMEM block (a grid of three, the last one
+        padded) over cells that straddle tile boundaries of a packed buffer
+        that is not a whole number of tiles."""
+        rng = np.random.default_rng(2500)
+        sizes = [640, 2048, 8, 1500]           # words per cell
+        bases = np.concatenate([[0], np.cumsum(sizes[:-1])])
+        packed = rng.integers(0, 2**32, sum(sizes), dtype=np.uint32)
+        packed |= rng.integers(0, 2**32, sum(sizes), dtype=np.uint32)
+        q = 2500
+        cell = np.sort(rng.integers(0, len(sizes), q))
+        h1 = rng.integers(0, 2**32, q, dtype=np.uint32)
+        h2 = rng.integers(0, 2**32, q, dtype=np.uint32) | 1
+        off = bases[cell].astype(np.int32)
+        nb = (np.asarray(sizes)[cell] * 32 - cell).astype(np.uint32)
+        args = tuple(map(jnp.asarray, (h1, h2, off, nb, packed)))
+        out = np.asarray(bloom_check_ragged(*args))
+        ref = np.asarray(bloom_check_ragged_ref(*args))
+        np.testing.assert_array_equal(out, ref)
+        assert 0 < out.mean() < 1
 
 
 class TestSsdScan:

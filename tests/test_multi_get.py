@@ -83,6 +83,8 @@ class TestMultiGetAgreement:
             assert after["batched_blob_reads"] > before["batched_blob_reads"]
             assert after["batched_kernel_lookups"] > \
                 before["batched_kernel_lookups"]
+            # ... and the kernel resolved every one of them itself.
+            assert after["kernel_unresolved"] == before["kernel_unresolved"]
             assert after["bloom_negative"] > before["bloom_negative"]
             # A repeat batch serves from the parsed-blob memo cache —
             # no new blob reads, and memoized cells skip the Bloom pass.
