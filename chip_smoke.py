@@ -1,22 +1,24 @@
 """Smoke run of TideDB's served read path on one TPU chip.
 
-Loads ``--keys`` uniform 32-byte hash keys with 1 KiB values (the paper's
-§6 shape) through ``put_many``, flushes and reopens the store so every
-cell is cold and backed by its index blob, then serves through
+Loads ``N_KEYS`` (1 M) uniform 32-byte hash keys with 1 KiB values (the
+paper's §6 shape) through ``put_many``, flushes and reopens the store so
+every cell is cold and backed by its index blob, then serves through
 ``KvBatchServer`` on one ``TideDB``:
 
 - one ``exists`` batch over present and absent keys, large enough for the
-  fused Bloom probe kernel (``bloom_check_ragged``);
-- ``get`` batches large enough for the optimistic lookup kernel;
+  fused Bloom probe on the device (``bloom_check_ragged``, an XLA gather);
+- ``get`` batches large enough for the optimistic lookup Pallas kernel;
 - puts of new keys and overwrites, then reads of them.
 
-Every answer is checked against a dict oracle.  Both kernels must have
-dispatched, compiled by Mosaic, and the lookup kernel must have resolved
-at least 99 % of the present keys by itself.
+Every answer is checked against a dict oracle.  Both device forms must
+have dispatched, the lookup kernel compiled by Mosaic; the Bloom probe must
+have rejected the absent keys and match the numpy probe bit for bit; the
+lookup kernel must have resolved at least 99 % of the present keys by
+itself.
 
 Run from the repo root on a machine with a TPU::
 
-    python3 chip_smoke.py [--seed 0] [--keys 1000000]
+    python3 chip_smoke.py [--seed 0]
 
 Without a TPU it exits non-zero before doing any work.  The figures it
 prints are smoke figures, not benchmark numbers; the last line of stdout is
@@ -40,11 +42,15 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from repro.core.tidestore import DbConfig, TideDB  # noqa: E402
+from repro.core.tidestore.bloom import (  # noqa: E402
+    key_hashes_many, probe_cells)
 from repro.kernels.bloom_check import ops as bloom_ops  # noqa: E402
 from repro.serving.engine import KvBatchServer  # noqa: E402
 
+N_KEYS = 1_000_000
 KEY_BYTES, VALUE_BYTES = 32, 1024
 MIN_KERNEL_RESOLVED = 0.99
+MIN_BLOOM_NEGATIVE = 0.95      # of absent keys; the filters' FP rate is ~0.1 %
 
 
 class SmokeFailure(RuntimeError):
@@ -96,6 +102,7 @@ def smoke(path: str, *, n_keys: int, seed: int, cfg=None,
                          get_batches, exists_batch, n_puts, log)
     figures["compile_s"] = compile_s[0]
     log(f"smoke: bloom_check_ragged dispatches={figures['bloom_dispatches']} "
+        f"absent keys rejected={figures['bloom_rejected']:.6f} "
         f"optimistic_lookup queries={figures['kernel_lookups']} "
         f"resolved by the kernel={figures['kernel_resolved']:.6f}")
     log(f"smoke: served exists {figures['exists_s']:.3f} s, "
@@ -142,13 +149,24 @@ def _serve(path, n_keys, seed, cfg, get_batch, get_batches, exists_batch,
         order = rng.permutation(len(probe))
         probe = [probe[i] for i in order]
         bloom0 = bloom_ops.ragged_dispatch_count
+        neg0 = db.stats()["bloom_negative"]
         reqs = [srv.submit_exists(k) for k in probe]
         figures["exists_s"] = _drain(srv, reqs)
         wrong = sum(r.found != (r.key in oracle) for r in reqs)
         _check(wrong == 0, f"{wrong} exists answers differ from the oracle")
         figures["bloom_dispatches"] = bloom_ops.ragged_dispatch_count - bloom0
         _check(figures["bloom_dispatches"] >= 1,
-               "the Bloom probe kernel never dispatched")
+               "the Bloom probe never dispatched to the device")
+        # Every probe positive goes on to the index, so the answers above
+        # cannot see a probe that lets absent keys through: check that it
+        # filtered them, and that its bits equal the numpy probe's.
+        negative = db.stats()["bloom_negative"] - neg0
+        figures["bloom_rejected"] = negative / len(absent)
+        _check(figures["bloom_rejected"] >= MIN_BLOOM_NEGATIVE,
+               f"the Bloom probe rejected {negative} of {len(absent)} "
+               f"absent keys")
+        _check(_bloom_parity(db, probe), "the Bloom probe's bits differ "
+               "from the numpy probe's on the store's filters")
 
         # gets of present keys: the optimistic lookup kernel's route.
         m0 = db.stats()
@@ -192,10 +210,27 @@ def _serve(path, n_keys, seed, cfg, get_batch, get_batches, exists_batch,
     return figures
 
 
+def _bloom_parity(db, keys: list) -> bool:
+    """One device probe of ``keys`` against every cell's filter, compared
+    bit for bit with the numpy probe of the same packed batch."""
+    ks = db.table.ks(0)
+    h1, h2 = key_hashes_many(keys)
+    q32 = np.frombuffer(b"".join(k[:4] for k in keys), ">u4")
+    cell = ks.uniform_split(q32.astype(np.uint32))[0]
+    ids = sorted(ks.cells)
+    blooms = [ks.cells[c].bloom for c in ids]
+    groups = [np.flatnonzero(cell == c) for c in ids]
+    before = bloom_ops.ragged_dispatch_count
+    device = probe_cells(blooms, h1, h2, groups)
+    _check(bloom_ops.ragged_dispatch_count > before,
+           "the parity probe did not dispatch to the device")
+    return bool((device == probe_cells(blooms, h1, h2, groups,
+                                       use_kernel=False)).all())
+
+
 def _mosaic_compiled() -> bool:
-    """Whether both kernels, in the mode this backend gives them, compile
-    to Mosaic custom calls rather than interpreted XLA."""
-    from repro.kernels.bloom_check.kernel import bloom_check_ragged
+    """Whether the lookup kernel, in the mode this backend gives it,
+    compiles to a Mosaic custom call rather than interpreted XLA."""
     from repro.kernels.optimistic_lookup.kernel import optimistic_lookup
 
     def spec(dtype, n=256):
@@ -206,16 +241,12 @@ def _mosaic_compiled() -> bool:
         q, k, n, (b, c, f), window=800)).lower(
             spec(u32), spec(u32, 4096), jax.ShapeDtypeStruct((), i32),
             spec(i32), spec(i32), spec(u32))
-    probe = jax.jit(bloom_check_ragged).lower(
-        spec(u32), spec(u32), spec(i32), spec(u32), spec(u32, 4096))
-    return all("tpu_custom_call" in c.compile().as_text()
-               for c in (lookup, probe))
+    return "tpu_custom_call" in lookup.compile().as_text()
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--keys", type=int, default=1_000_000)
     args = ap.parse_args(argv)
 
     if jax.default_backend() != "tpu":
@@ -227,10 +258,10 @@ def main(argv=None) -> int:
     dev = jax.devices()[0]
     print(f"smoke: device_kind={dev.device_kind} devices={len(jax.devices())} "
           f"compile cache at {cache_dir}")
-    _check(_mosaic_compiled(), "the kernels did not compile with Mosaic")
-    print("smoke: both kernels compile to Mosaic custom calls")
+    _check(_mosaic_compiled(), "the lookup kernel did not compile with Mosaic")
+    print("smoke: the lookup kernel compiles to a Mosaic custom call")
     with tempfile.TemporaryDirectory(prefix="tidedb-smoke-") as d:
-        smoke(d, n_keys=args.keys, seed=args.seed)
+        smoke(d, n_keys=N_KEYS, seed=args.seed)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -3,11 +3,11 @@
 The paper resolves ``exists`` queries from memory without touching the index
 or the Value WAL; this is the 15.6× existence-check win.  The bitset is a
 flat uint32 word array with k double-hashed probes — **bit-identical** to the
-``kernels/bloom_check`` Pallas kernel's layout and probe arithmetic
+``kernels/bloom_check`` device probe's layout and probe arithmetic
 (``idx_i = (h1 + i·h2) mod 2³² mod nbits``, word = idx>>5, bit = idx&31), so
 a batch of queries can be tested either host-side (numpy) or through the
-kernel's ops wrapper with exactly the same answers — no false negatives can
-be introduced by switching paths.
+device probe's ops wrapper with exactly the same answers — no false
+negatives can be introduced by switching paths.
 
 ``probe_cells`` is the fused multi-cell entry: the bit arrays of every
 touched cell pack into one buffer, each query carries its cell's word

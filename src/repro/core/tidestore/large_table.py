@@ -102,10 +102,21 @@ class Keyspace:
             self._prefix_lock = threading.Lock()
 
     # ---------------------------------------------------------- cell lookup
+    def uniform_split(self, h):
+        """The uniform mapping of u32 key prefixes ``h`` (an int or a u32
+        array): the cell is (h·n_cells) >> 32, and (h·n_cells) mod 2³² is
+        the prefix's position inside that cell's key range, scaled to 2³².
+        Returns (cell, frac)."""
+        if isinstance(h, np.ndarray):
+            x = h.astype(np.uint64) * np.uint64(self.cfg.n_cells)
+            return (x >> np.uint64(32)).astype(np.int64), x.astype(np.uint32)
+        x = h * self.cfg.n_cells
+        return x >> 32, x & 0xFFFFFFFF
+
     def cell_id_for_key(self, key: bytes) -> object:
         if self.cfg.distribution == "uniform":
             h = int.from_bytes(key[:4].ljust(4, b"\x00"), "big")
-            return (h * self.cfg.n_cells) >> 32
+            return self.uniform_split(h)[0]
         return key[: self.cfg.prefix_len]
 
     def cell_for_key(self, key: bytes, create: bool = True) -> Optional[Cell]:
@@ -688,14 +699,12 @@ class LargeTable:
         if use_kernel and len(queries) >= _KERNEL_MIN_QUERIES:
             from repro.kernels.optimistic_lookup.ops import lookup_indices_batch
             # Each query interpolates inside its own cell's slice of the
-            # concatenation: for h = the key's u32 prefix, the cell is
-            # (h·n_cells) >> 32 and (h·n_cells) mod 2³² is the key's
-            # fractional position inside that cell's key range.
+            # concatenation, from its position inside the cell's key range.
             sizes = [len(p[1]) for p in parts]
             per_q = [len(p[0]) for p in parts]
             base = np.repeat(np.cumsum([0] + sizes[:-1]), per_q)
             count = np.repeat(sizes, per_q)
-            frac = (q32.astype(np.uint64) * ks.cfg.n_cells).astype(np.uint32)
+            frac = ks.uniform_split(q32)[1]
             idx, found, unresolved = lookup_indices_batch(
                 q32, u32, segments=(base, count, frac),
                 window=ks.cfg.window_entries)

@@ -1,75 +1,21 @@
-"""bloom_check — k-probe Bloom-filter membership on TPU.
+"""bloom_check — k-probe Bloom-filter membership on the device.
 
 Per-cell Bloom filters resolve negative lookups without touching the index
 (§3.2 step 2, the 15.6× existence-check win).  Queries arrive as (h1, h2)
 64-bit hash halves and probe k derived slots of their cell's bitset:
 idx_i = (h1 + i·h2) mod nbits, word = idx >> 5, bit = idx & 31.
 
-The packed bitset stays in HBM as (W/1024, 8, 128) tiles of 1024 words, so
-no filter size is bounded by VMEM.  Queries go through SMEM in blocks; for
-each sub-batch of ``_SUB`` queries the kernel DMAs the one tile holding
-each probe's word into VMEM (all ``_SUB``·k copies in flight at once),
-then reads every probe's bit with a masked select over its tile.  VMEM use
-is ``_SUB``·k tiles whatever the store's size.
+The probe is a plain ``jnp`` gather that XLA compiles for the device; no
+Pallas kernel.  On a TPU v5e a Pallas form that DMA'd the 4 KiB tile
+holding each probed word into VMEM (a 1-D gather does not lower in Mosaic)
+was slower than XLA's gather at every shape the store dispatches, and it
+needed its own blocking to bound VMEM.  XLA keeps the bitset in HBM and
+bounds VMEM itself.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from .. import platform
-
-TILE = 1024          # u32 words per (8, 128) tile
-_SUB = 32            # queries whose probe tiles share VMEM at once
-_BLOCK = 1024        # queries per grid step (the SMEM tiling of long arrays)
-
-
-def _ragged_kernel(h1_ref, h2_ref, off_ref, nbits_ref, bits_hbm, out_ref,
-                   tiles, sem, lane, shift, red, *, k: int, block: int):
-    flat = (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) * 128
-            + jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1))
-
-    def sub_batch(s, carry):
-        def fetch(j, c):
-            q = s * _SUB + j
-            h1, h2, nb = h1_ref[q], h2_ref[q], nbits_ref[q]
-            for i in range(k):
-                idx = (h1 + jnp.uint32(i) * h2) % nb
-                word = off_ref[q] + (idx >> jnp.uint32(5)).astype(jnp.int32)
-                slot = j * k + i
-                lane[slot] = word & (TILE - 1)
-                shift[slot] = (idx & jnp.uint32(31)).astype(jnp.int32)
-                pltpu.make_async_copy(bits_hbm.at[word >> 10],
-                                      tiles.at[slot], sem).start()
-            return c
-
-        jax.lax.fori_loop(0, _SUB, fetch, 0)
-
-        def drain(_, c):        # every copy moves one tile on one semaphore
-            pltpu.make_async_copy(bits_hbm.at[0], tiles.at[0], sem).wait()
-            return c
-
-        jax.lax.fori_loop(0, _SUB * k, drain, 0)
-
-        def test(j, c):
-            set_bits = jnp.zeros((8, 128), jnp.int32)
-            for i in range(k):
-                slot = j * k + i
-                bit = jax.lax.shift_right_logical(tiles[slot],
-                                                  shift[slot]) & 1
-                set_bits = set_bits + jnp.where(flat == lane[slot], bit, 0)
-            red[0] = jnp.sum(set_bits)
-            out_ref[s * _SUB + j] = (red[0] == k).astype(jnp.int32)
-            return c
-
-        jax.lax.fori_loop(0, _SUB, test, 0)
-        return carry
-
-    jax.lax.fori_loop(0, block // _SUB, sub_batch, 0)
 
 
 def bloom_check_ragged(h1: jax.Array, h2: jax.Array, off: jax.Array,
@@ -85,30 +31,14 @@ def bloom_check_ragged(h1: jax.Array, h2: jax.Array, off: jax.Array,
 
     h1, h2, off, nbits (Q,); bits (total_words,) u32 → (Q,) bool.
     """
-    q, w = h1.shape[0], bits.shape[0]
-    # An SMEM block is the whole query axis up to _BLOCK, else _BLOCK.
-    block = -(-q // _SUB) * _SUB if q <= _BLOCK else _BLOCK
-    qp = -(-q // block) * block
-    wp = -(-w // TILE) * TILE
-    pad = qp - q        # padding queries probe bit 0 of word 0, sliced off
-    cols = [jnp.pad(jnp.asarray(a, dt), (0, pad), constant_values=v)
-            for a, dt, v in ((h1, jnp.uint32, 0), (h2, jnp.uint32, 1),
-                             (off, jnp.int32, 0), (nbits, jnp.uint32, 32))]
-    words = jnp.pad(jax.lax.bitcast_convert_type(
-        jnp.asarray(bits, jnp.uint32), jnp.int32), (0, wp - w))
-    smem = pl.BlockSpec((block,), lambda g: (g,), memory_space=pltpu.SMEM)
-    out = pl.pallas_call(
-        functools.partial(_ragged_kernel, k=k, block=block),
-        grid=(qp // block,),
-        in_specs=[smem] * 4 + [pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=smem,
-        out_shape=jax.ShapeDtypeStruct((qp,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((_SUB * k, 8, 128), jnp.int32),
-                        pltpu.SemaphoreType.DMA(()),
-                        pltpu.SMEM((_SUB * k,), jnp.int32),
-                        pltpu.SMEM((_SUB * k,), jnp.int32),
-                        pltpu.SMEM((1,), jnp.int32)],
-        interpret=platform.interpret(),
-    )(*cols, words.reshape(wp // TILE, 8, 128))
-    return out[:q].astype(jnp.bool_)
-
+    h1 = jnp.asarray(h1, jnp.uint32)
+    h2 = jnp.asarray(h2, jnp.uint32)
+    nbits = jnp.asarray(nbits, jnp.uint32)
+    off = jnp.asarray(off, jnp.int32)
+    result = jnp.ones(h1.shape, jnp.bool_)
+    for i in range(k):
+        idx = (h1 + jnp.uint32(i) * h2) % nbits
+        word = bits[off + (idx >> jnp.uint32(5)).astype(jnp.int32)]
+        result = result & (((word >> (idx & jnp.uint32(31)))
+                            & jnp.uint32(1)) == jnp.uint32(1))
+    return result

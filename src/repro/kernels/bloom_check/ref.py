@@ -1,29 +1,9 @@
-"""Oracle for bloom_check."""
+"""Bitset builder for bloom_check tests.  The reference probe is the store's
+numpy pass, ``repro.core.tidestore.bloom._probe_host``: it shares no code
+with the device form."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-
-
-def bloom_check_ref(h1, h2, bits, *, k: int = 7, nbits=None):
-    nbits = nbits if nbits is not None else bits.shape[0] * 32
-    result = jnp.ones(h1.shape, jnp.bool_)
-    for i in range(k):
-        idx = (h1 + jnp.uint32(i) * h2) % jnp.uint32(nbits)
-        word = bits[(idx >> jnp.uint32(5)).astype(jnp.int32)]
-        result = result & (((word >> (idx & jnp.uint32(31)))
-                            & jnp.uint32(1)) == jnp.uint32(1))
-    return result
-
-
-def bloom_check_ragged_ref(h1, h2, off, nbits, bits, *, k: int = 7):
-    """Oracle for the fused ragged probe: per-query modulus + word base."""
-    result = jnp.ones(h1.shape, jnp.bool_)
-    for i in range(k):
-        idx = (h1 + jnp.uint32(i) * h2) % nbits
-        word = bits[off + (idx >> jnp.uint32(5)).astype(jnp.int32)]
-        result = result & (((word >> (idx & jnp.uint32(31)))
-                            & jnp.uint32(1)) == jnp.uint32(1))
-    return result
 
 
 def bloom_add_ref(h1, h2, bits, *, k: int = 7, nbits=None):

@@ -11,10 +11,14 @@ def test_smoke_flow_serves_and_checks_on_cpu(tmp_path):
                                exists_batch=2048, n_puts=64,
                                log=lambda *_: None)
     assert figures["bloom_dispatches"] == 1
+    assert figures["bloom_rejected"] >= chip_smoke.MIN_BLOOM_NEGATIVE
     assert figures["kernel_lookups"] == 2 * 512
     assert figures["kernel_resolved"] >= chip_smoke.MIN_KERNEL_RESOLVED
 
 
-def test_refuses_without_a_tpu(capsys):
+def test_refuses_without_a_tpu(capsys, monkeypatch):
+    # Whatever the host's backend, the smoke must stop before any work.
+    monkeypatch.setattr(chip_smoke.jax, "default_backend", lambda: "cpu")
+    monkeypatch.setattr(chip_smoke, "smoke", None)
     assert chip_smoke.main([]) != 0
     assert capsys.readouterr().out == ""

@@ -7,10 +7,9 @@ import pytest
 
 from hypothesis_compat import HealthCheck, given, settings, st
 
+from repro.core.tidestore.bloom import _probe_host
 from repro.kernels.bloom_check.kernel import bloom_check_ragged
-from repro.kernels.bloom_check.ref import (bloom_add_ref,
-                                           bloom_check_ragged_ref,
-                                           bloom_check_ref)
+from repro.kernels.bloom_check.ref import bloom_add_ref
 from repro.kernels.optimistic_lookup.kernel import optimistic_lookup
 from repro.kernels.optimistic_lookup.ops import lookup_indices_batch
 from repro.kernels.optimistic_lookup.ref import optimistic_lookup_ref
@@ -22,12 +21,21 @@ SETTINGS = settings(max_examples=15, deadline=None,
 
 
 def bloom_check(h1, h2, bits, *, k=7, nbits=None):
-    """One filter probed through the ragged kernel: every query on one
-    cell at word 0."""
+    """One filter probed through the ragged device probe: every query on
+    one cell at word 0."""
     q = h1.shape[0]
     nbits = nbits if nbits is not None else bits.shape[0] * 32
     return bloom_check_ragged(h1, h2, jnp.zeros(q, jnp.int32),
                               jnp.full(q, nbits, jnp.uint32), bits, k=k)
+
+
+def bloom_check_host(h1, h2, bits, *, k=7, nbits=None):
+    """The same probe through the store's numpy pass — the reference."""
+    q = np.asarray(h1).shape[0]
+    nbits = nbits if nbits is not None else np.asarray(bits).shape[0] * 32
+    return _probe_host(np.asarray(h1, np.uint32), np.asarray(h2, np.uint32),
+                       np.zeros(q, np.int32), np.full(q, nbits, np.uint32),
+                       np.asarray(bits, np.uint32), k)
 
 
 def _mk_arena(key, B, NB, blk, KH, dk, dv, dtype):
@@ -262,7 +270,7 @@ class TestBloomCheck:
         h2q = jnp.concatenate([h2a, jnp.asarray(
             rng.integers(0, 2**32, 200, dtype=np.uint32) | 1)])
         out = bloom_check(h1q, h2q, bits, k=k)
-        ref = bloom_check_ref(h1q, h2q, bits, k=k)
+        ref = bloom_check_host(h1q, h2q, bits, k=k)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
         assert bool(jnp.all(out[:nadd]))          # no false negatives
         assert float(jnp.mean(out[nadd:])) < 0.35  # bounded false positives
@@ -317,17 +325,15 @@ class TestBloomCheckRagged:
         ([512], [200]),                           # single cell
     ])
     def test_vs_ref_and_flat_percell(self, nwords_list, nadd_list):
-        """The fused kernel equals its jnp oracle AND the per-cell flat
-        kernel sliced back out — fusion introduces no false negatives."""
+        """The fused probe equals the numpy probe AND the per-cell flat
+        probe sliced back out — fusion introduces no false negatives."""
         cells = self._cells(7, nwords_list, nadd_list)
         h1, h2, off, nb, packed, bounds = self._ragged_inputs(cells, 25, 7)
         out = bloom_check_ragged(jnp.asarray(h1), jnp.asarray(h2),
                                  jnp.asarray(off), jnp.asarray(nb),
                                  jnp.asarray(packed))
-        ref = bloom_check_ragged_ref(jnp.asarray(h1), jnp.asarray(h2),
-                                     jnp.asarray(off), jnp.asarray(nb),
-                                     jnp.asarray(packed))
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        ref = _probe_host(h1, h2, off, nb, packed, 7)
+        np.testing.assert_array_equal(np.asarray(out), ref)
         pos = 0
         for (bits, h1a, h2a, nbits), (nadd, n_miss) in zip(cells, bounds):
             q = nadd + n_miss
@@ -354,17 +360,15 @@ class TestBloomCheckRagged:
         pos = 0
         for (bits, _, _, nbits), (nadd, n_miss) in zip(cells, bounds):
             q = nadd + n_miss
-            flat = bloom_check_ref(jnp.asarray(h1[pos:pos + q]),
-                                   jnp.asarray(h2[pos:pos + q]),
-                                   jnp.asarray(bits), nbits=nbits)
-            np.testing.assert_array_equal(out[pos:pos + q], np.asarray(flat))
+            flat = bloom_check_host(h1[pos:pos + q], h2[pos:pos + q], bits,
+                                    nbits=nbits)
+            np.testing.assert_array_equal(out[pos:pos + q], flat)
             pos += q
 
 
     def test_blocked_grid_matches_ref(self):
-        """More queries than one SMEM block (a grid of three, the last one
-        padded) over cells that straddle tile boundaries of a packed buffer
-        that is not a whole number of tiles."""
+        """A batch that is not a power of two over cells whose moduli are
+        not powers of two, packed into a buffer of odd size."""
         rng = np.random.default_rng(2500)
         sizes = [640, 2048, 8, 1500]           # words per cell
         bases = np.concatenate([[0], np.cumsum(sizes[:-1])])
@@ -378,7 +382,7 @@ class TestBloomCheckRagged:
         nb = (np.asarray(sizes)[cell] * 32 - cell).astype(np.uint32)
         args = tuple(map(jnp.asarray, (h1, h2, off, nb, packed)))
         out = np.asarray(bloom_check_ragged(*args))
-        ref = np.asarray(bloom_check_ragged_ref(*args))
+        ref = _probe_host(h1, h2, off, nb, packed, 7)
         np.testing.assert_array_equal(out, ref)
         assert 0 < out.mean() < 1
 
