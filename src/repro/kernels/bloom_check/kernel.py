@@ -6,11 +6,10 @@ Per-cell Bloom filters resolve negative lookups without touching the index
 idx_i = (h1 + i·h2) mod nbits, word = idx >> 5, bit = idx & 31.
 
 The probe is a plain ``jnp`` gather that XLA compiles for the device; no
-Pallas kernel.  On a TPU v5e a Pallas form that DMA'd the 4 KiB tile
-holding each probed word into VMEM (a 1-D gather does not lower in Mosaic)
-was slower than XLA's gather at every shape the store dispatches, and it
-needed its own blocking to bound VMEM.  XLA keeps the bitset in HBM and
-bounds VMEM itself.
+Pallas kernel.  A 1-D gather does not lower in Mosaic, so a Pallas form
+has to DMA the 4 KiB tile holding each probed word into VMEM and block its
+queries to bound VMEM; XLA keeps the bitset in HBM and bounds VMEM itself.
+PERF.md records the chip comparison of the two forms.
 """
 from __future__ import annotations
 
