@@ -186,7 +186,6 @@ def _db_probe(csv) -> dict:
 
     from repro.core.tidestore import DbConfig, KeyspaceConfig, TideDB
     from repro.core.tidestore.wal import WalConfig
-    from repro.kernels.bloom_check import ops as bloom_ops
 
     d = tempfile.mkdtemp(prefix="bench-kvexists-")
     # blob_cache_bytes=0 keeps the Bloom gate live on every call (a
@@ -209,9 +208,9 @@ def _db_probe(csv) -> dict:
             db.snapshot_now(flush_threshold=1)
             batch = present[:512] + absent[:512]
             db.multi_exists(batch)            # warm jit shapes + blob memo
-            before = bloom_ops.ragged_dispatch_count
+            before = db.stats()["bloom_dispatches"]
             db.multi_exists(batch)
-            dispatches = bloom_ops.ragged_dispatch_count - before
+            dispatches = db.stats()["bloom_dispatches"] - before
             dt_b = _best(lambda: db.multi_exists(batch), 3)
             dt_s = _best(lambda: [db.exists(k) for k in batch], 3)
             row = {"batch": len(batch),

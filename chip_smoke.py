@@ -44,7 +44,6 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 from repro.core.tidestore import DbConfig, TideDB  # noqa: E402
 from repro.core.tidestore.bloom import (  # noqa: E402
     key_hashes_many, probe_cells)
-from repro.kernels.bloom_check import ops as bloom_ops  # noqa: E402
 from repro.serving.engine import KvBatchServer  # noqa: E402
 
 N_KEYS = 1_000_000
@@ -148,13 +147,13 @@ def _serve(path, n_keys, seed, cfg, get_batch, get_batches, exists_batch,
         probe = present + absent
         order = rng.permutation(len(probe))
         probe = [probe[i] for i in order]
-        bloom0 = bloom_ops.ragged_dispatch_count
+        bloom0 = db.stats()["bloom_dispatches"]
         neg0 = db.stats()["bloom_negative"]
         reqs = [srv.submit_exists(k) for k in probe]
         figures["exists_s"] = _drain(srv, reqs)
         wrong = sum(r.found != (r.key in oracle) for r in reqs)
         _check(wrong == 0, f"{wrong} exists answers differ from the oracle")
-        figures["bloom_dispatches"] = bloom_ops.ragged_dispatch_count - bloom0
+        figures["bloom_dispatches"] = db.stats()["bloom_dispatches"] - bloom0
         _check(figures["bloom_dispatches"] >= 1,
                "the Bloom probe never dispatched to the device")
         # Every probe positive goes on to the index, so the answers above
@@ -220,12 +219,11 @@ def _bloom_parity(db, keys: list) -> bool:
     ids = sorted(ks.cells)
     blooms = [ks.cells[c].bloom for c in ids]
     groups = [np.flatnonzero(cell == c) for c in ids]
-    before = bloom_ops.ragged_dispatch_count
-    device = probe_cells(blooms, h1, h2, groups)
-    _check(bloom_ops.ragged_dispatch_count > before,
+    device, copies = probe_cells(blooms, h1, h2, groups)
+    _check(copies.dispatches > 0,
            "the parity probe did not dispatch to the device")
-    return bool((device == probe_cells(blooms, h1, h2, groups,
-                                       use_kernel=False)).all())
+    host, _ = probe_cells(blooms, h1, h2, groups, use_kernel=False)
+    return bool((device == host).all())
 
 
 def _mosaic_compiled() -> bool:

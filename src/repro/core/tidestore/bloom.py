@@ -22,6 +22,8 @@ import struct
 
 import numpy as np
 
+from repro.kernels.padding import Copies
+
 # Below this many queries the jitted kernel's dispatch overhead dominates;
 # the numpy path computes the identical answer in a few microseconds.
 _KERNEL_MIN_BATCH = 64
@@ -110,8 +112,9 @@ class BloomFilter:
             if not len(keys):
                 return np.zeros(0, dtype=bool)
             h1, h2 = key_hashes_many(keys)
+        # A lone filter belongs to no store, so no counter takes its copies.
         return probe_cells([self], h1, h2, [np.arange(len(h1))],
-                           use_kernel=use_kernel)
+                           use_kernel=use_kernel)[0]
 
     @property
     def nbytes(self) -> int:
@@ -158,8 +161,10 @@ def _probe_host(h1: np.ndarray, h2: np.ndarray, off: np.ndarray,
 
 
 def probe_cells(cells, h1: np.ndarray, h2: np.ndarray, groups,
-                use_kernel: bool = True) -> np.ndarray:
-    """Fused membership across many cells' filters → (Q,) bool.
+                use_kernel: bool = True) -> tuple[np.ndarray, Copies]:
+    """Fused membership across many cells' filters → ((Q,) bool, copies),
+    ``copies`` summing the kernel dispatches and bytes copied (all zero
+    where the numpy pass answered).
 
     ``cells[i]`` is a ``BloomFilter`` (or ``None`` to skip) and
     ``groups[i]`` the indices into ``h1``/``h2`` of the queries probing it —
@@ -185,8 +190,9 @@ def probe_cells(cells, h1: np.ndarray, h2: np.ndarray, groups,
     h1 = np.asarray(h1, dtype=np.uint32)
     h2 = np.asarray(h2, dtype=np.uint32)
     out = np.zeros(len(h1), dtype=bool)
+    copies = Copies()
     if not len(h1):
-        return out
+        return out, copies
     by_k: dict[int, list] = {}
     for cell, g in zip(cells, groups):
         g = np.asarray(g, dtype=np.int64)
@@ -211,7 +217,9 @@ def probe_cells(cells, h1: np.ndarray, h2: np.ndarray, groups,
                                  for c, g in members])
         if use_kernel and sel.size >= _KERNEL_MIN_BATCH * len(members):
             from repro.kernels.bloom_check.ops import probe_cells_batch
-            out[sel] = probe_cells_batch(h1[sel], h2[sel], off, nb, bits, k=k)
+            out[sel], c = probe_cells_batch(h1[sel], h2[sel], off, nb,
+                                            bits, k=k)
+            copies = Copies(*map(sum, zip(copies, c)))
         else:
             out[sel] = _probe_host(h1[sel], h2[sel], off, nb, bits, k)
-    return out
+    return out, copies

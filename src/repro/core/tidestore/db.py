@@ -21,6 +21,8 @@ from typing import Optional
 
 import msgpack
 
+from repro.tracing import span, traced
+
 from .api import (KeyspaceHandle, PruneOptions, ReadOptions, WriteBatch,
                   WriteOptions, coerce_batch)
 from .cache import LruCache
@@ -575,6 +577,7 @@ class TideDB:
                 self.value_wal.flush()
         return positions
 
+    @traced("db.put_many")
     def put_many(self, items, keyspace=0, epoch: int = 0,
                  opts: Optional[WriteOptions] = None) -> list:
         """Batched ``put`` (§3.1 vectorized): ``items`` is a list of
@@ -782,6 +785,7 @@ class TideDB:
                                  pos_live=self.value_wal.pos_live)
 
     # -------------------------------------------------------- batched reads
+    @traced("db.multi_get")
     def multi_get(self, keys, keyspace=0,
                   opts: Optional[ReadOptions] = None) -> list:
         """Batched point lookups (§3.2, batched): resolve a whole batch of
@@ -801,17 +805,18 @@ class TideDB:
         min_live = self._min_live(opts)
         self.metrics.add(batched_read_keys=len(keys))
         results: list = [None] * len(keys)
-        cks = [self._cache_key(ks_id, k) for k in keys]
-        if opts.min_live_pin is None:
-            cached = self.cache.get_many(cks)
-        else:
-            # Pinned reads bypass the cache (cached values carry no
-            # position to check against the pin).
-            cached = [None] * len(keys)
-        miss_idx = [i for i, v in enumerate(cached) if v is None]
-        for i, v in enumerate(cached):
-            if v is not None:
-                results[i] = v
+        with span("db.cache_sweep"):
+            cks = [self._cache_key(ks_id, k) for k in keys]
+            if opts.min_live_pin is None:
+                cached = self.cache.get_many(cks)
+            else:
+                # Pinned reads bypass the cache (cached values carry no
+                # position to check against the pin).
+                cached = [None] * len(keys)
+            miss_idx = [i for i, v in enumerate(cached) if v is None]
+            for i, v in enumerate(cached):
+                if v is not None:
+                    results[i] = v
         self.metrics.add(cache_hits=len(keys) - len(miss_idx),
                          cache_misses=len(miss_idx))
         if not miss_idx:
@@ -855,9 +860,11 @@ class TideDB:
                 results[i] = value
                 fills.append((cks[i], value))
         if opts.fill_cache:
-            self.cache.put_many(fills)   # single cache fill at the end
+            with span("db.cache_fill"):
+                self.cache.put_many(fills)   # single cache fill at the end
         return results
 
+    @traced("db.multi_exists")
     def multi_exists(self, keys, keyspace=0,
                      opts: Optional[ReadOptions] = None) -> list:
         """Batched existence checks resolved entirely from index state —
@@ -876,15 +883,16 @@ class TideDB:
             self.system.note_reads(ks_id, keys, kind="exists")
         self.metrics.add(batched_read_keys=len(keys))
         results = [False] * len(keys)
-        if opts.min_live_pin is None:
-            cached = self.cache.get_many(
-                [self._cache_key(ks_id, k) for k in keys])
-        else:
-            cached = [None] * len(keys)      # pinned: bypass the cache
-        miss_idx = [i for i, v in enumerate(cached) if v is None]
-        for i, v in enumerate(cached):
-            if v is not None:
-                results[i] = True
+        with span("db.cache_sweep"):
+            if opts.min_live_pin is None:
+                cached = self.cache.get_many(
+                    [self._cache_key(ks_id, k) for k in keys])
+            else:
+                cached = [None] * len(keys)      # pinned: bypass the cache
+            miss_idx = [i for i, v in enumerate(cached) if v is None]
+            for i, v in enumerate(cached):
+                if v is not None:
+                    results[i] = True
         self.metrics.add(cache_hits=len(keys) - len(miss_idx))
         if not miss_idx:
             return results

@@ -14,6 +14,8 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
+from repro.tracing import span
+
 from .index import FORMATS, is_tombstone, real_pos
 from .large_table import Cell, CellState, LargeTable
 from .util import Metrics
@@ -64,7 +66,8 @@ class Flusher:
 
     def _safe_flush(self, ks_id: int, cell: Cell) -> None:
         try:
-            self.flush_cell(ks_id, cell)
+            with span("bg.flush_cell"):
+                self.flush_cell(ks_id, cell)
         except Exception as e:
             # I/O errors with a registered handler are *expected* failures
             # (disk full, injected faults): the handler classifies them and

@@ -21,6 +21,8 @@ from typing import Callable, Iterator, Optional
 
 import numpy as np
 
+from repro.tracing import span, traced
+
 from .bloom import BloomFilter
 from .cache import BlobArrayCache
 from .index import (FORMATS, blob_to_arrays, entry_size, is_tombstone,
@@ -219,6 +221,7 @@ class LargeTable:
                 cell.state = CellState.DIRTY_UNLOADED   # buffer only (§4.1)
             return True
 
+    @traced("table.apply_many")
     def apply_many(self, items) -> int:
         """Batched ``apply`` (§3.1 vectorized index update): ``items`` is a
         list of (ks_id, key, pos_marker) in WAL-position order.
@@ -483,6 +486,7 @@ class LargeTable:
         return pos_live is None or pos_live(p)
 
     # -------------------------------------------------------- batched reads
+    @traced("table.bloom_pass")
     def _fused_bloom_pass(self, ks: Keyspace, probe, out, use_kernel) -> list:
         """ONE ragged Bloom probe across every (cell, keys, bloom) group in
         ``probe``: keys hash once, the touched cells' bitsets pack into one
@@ -506,10 +510,13 @@ class LargeTable:
         for _, keys, _ in probe:
             groups.append(np.arange(base, base + len(keys)))
             base += len(keys)
-        ok = probe_cells([bloom for _, _, bloom in probe], h1, h2, groups,
-                         use_kernel=use_kernel)
+        ok, copies = probe_cells([bloom for _, _, bloom in probe], h1, h2,
+                                 groups, use_kernel=use_kernel)
         self.metrics.add(fused_bloom_probes=1,
-                         bloom_negative=int(len(flat) - ok.sum()))
+                         bloom_negative=int(len(flat) - ok.sum()),
+                         bloom_dispatches=copies.dispatches,
+                         h2d_bytes=copies.h2d_bytes,
+                         d2h_bytes=copies.d2h_bytes)
         survivors = []
         for (cell, keys, _), g in zip(probe, groups):
             hits = ok[g]
@@ -521,6 +528,7 @@ class LargeTable:
                 survivors.append((cell, kept))
         return survivors
 
+    @traced("table.resolve")
     def get_positions_batch(self, ks_id: int, keys, *, use_bloom: bool = True,
                             use_kernel: bool = True) -> list:
         """Batched key → position-marker resolution (§3.2 batched).
@@ -667,17 +675,23 @@ class LargeTable:
         for cell, missing, dpos, dlen, dcount in blob_cells:
             ent = self.blob_cache.get(dpos)
             if ent is None:
-                pread = self._bounded_pread(dpos, dlen)
-                buf, n = load_blob_arrays(pread, dcount, key_len, fmt)
-                if n < dcount:          # short read (GC race): per-key retry
-                    perkey.extend((cell, k) for k in missing)
-                    continue
-                u32_c, pos_c, keys_c, nbytes = blob_to_arrays(buf, n, key_len)
-                if cell.disk_pos == dpos:
-                    # A flush that raced this read already invalidated dpos
-                    # and swapped the cell to a new blob; memoizing the old
-                    # one would strand dead budget until LRU aging.
-                    self.blob_cache.put(dpos, (u32_c, pos_c, keys_c), nbytes)
+                with span("table.blob_load"):
+                    with span("wal.index_pread"):
+                        buf, n = load_blob_arrays(
+                            self._bounded_pread(dpos, dlen), dcount,
+                            key_len, fmt)
+                    if n < dcount:      # short read (GC race): per-key retry
+                        perkey.extend((cell, k) for k in missing)
+                        continue
+                    u32_c, pos_c, keys_c, nbytes = blob_to_arrays(buf, n,
+                                                                  key_len)
+                    if cell.disk_pos == dpos:
+                        # A flush that raced this read already invalidated
+                        # dpos and swapped the cell to a new blob; memoizing
+                        # the old one would strand dead budget until LRU
+                        # aging.
+                        self.blob_cache.put(dpos, (u32_c, pos_c, keys_c),
+                                            nbytes)
                 self.metrics.add(batched_blob_reads=1)
             else:
                 u32_c, pos_c, keys_c = ent
@@ -705,51 +719,57 @@ class LargeTable:
             base = np.repeat(np.cumsum([0] + sizes[:-1]), per_q)
             count = np.repeat(sizes, per_q)
             frac = ks.uniform_split(q32)[1]
-            idx, found, unresolved = lookup_indices_batch(
+            idx, found, unresolved, copies = lookup_indices_batch(
                 q32, u32, segments=(base, count, frac),
                 window=ks.cfg.window_entries)
             self.metrics.add(batched_kernel_lookups=len(queries),
-                             kernel_unresolved=unresolved)
+                             kernel_unresolved=unresolved,
+                             lookup_dispatches=copies.dispatches,
+                             h2d_bytes=copies.h2d_bytes,
+                             d2h_bytes=copies.d2h_bytes)
         else:
             idx = np.searchsorted(u32, q32, side="left").astype(np.int64)
             safe = np.minimum(idx, total - 1)
             found = (idx < total) & (u32[safe] == q32)
         self.metrics.add(index_lookups=len(queries))
-        # Vectorized full-key verification: in the common case (no u32
-        # prefix collision) the landing index either IS the query key or
-        # the key is absent — one gathered row compare decides all queries
-        # at once.  Only collision runs fall back to the per-query walk.
-        idx = np.asarray(idx, dtype=np.int64)
-        found = np.asarray(found, dtype=bool)
-        safe = np.minimum(idx, total - 1)
-        if all(len(k) == key_len for k in queries):
-            qmat = np.frombuffer(b"".join(queries),
-                                 np.uint8).reshape(len(queries), key_len)
-            karr = np.frombuffer(keybuf, np.uint8).reshape(total, key_len)
-            exact = found & (karr[safe] == qmat).all(axis=1)
-        else:
-            exact = np.zeros(len(queries), dtype=bool)
-        has_run = found & ~exact
-        for qi in np.flatnonzero(exact):
-            out[queries[qi]] = int(pos[safe[qi]])
-        for qi in np.flatnonzero(~found):
-            out[queries[qi]] = None
-        for qi in np.flatnonzero(has_run):
-            k, q, j = queries[qi], q32[qi], int(idx[qi])
-            marker = None
-            # The kernel may land mid-run when several keys share a u32
-            # prefix (its window rank counts strictly-smaller entries
-            # from the window start, not the array start): rewind to the
-            # run's first entry, then walk forward comparing full keys.
-            while j > 0 and u32[j - 1] == q:
-                j -= 1
-            while j < total and u32[j] == q:
-                if keybuf[j * key_len:(j + 1) * key_len] == k:
-                    marker = int(pos[j])
-                    break
-                j += 1
-            out[k] = marker
+        with span("table.verify"):
+            # Vectorized full-key verification: in the common case (no u32
+            # prefix collision) the landing index either IS the query key
+            # or the key is absent — one gathered row compare decides all
+            # queries at once.  Only collision runs fall back to the
+            # per-query walk.
+            idx = np.asarray(idx, dtype=np.int64)
+            found = np.asarray(found, dtype=bool)
+            safe = np.minimum(idx, total - 1)
+            if all(len(k) == key_len for k in queries):
+                qmat = np.frombuffer(b"".join(queries),
+                                     np.uint8).reshape(len(queries), key_len)
+                karr = np.frombuffer(keybuf, np.uint8).reshape(total, key_len)
+                exact = found & (karr[safe] == qmat).all(axis=1)
+            else:
+                exact = np.zeros(len(queries), dtype=bool)
+            has_run = found & ~exact
+            for qi in np.flatnonzero(exact):
+                out[queries[qi]] = int(pos[safe[qi]])
+            for qi in np.flatnonzero(~found):
+                out[queries[qi]] = None
+            for qi in np.flatnonzero(has_run):
+                k, q, j = queries[qi], q32[qi], int(idx[qi])
+                marker = None
+                # The kernel may land mid-run when several keys share a u32
+                # prefix (its window rank counts strictly-smaller entries
+                # from the window start, not the array start): rewind to the
+                # run's first entry, then walk forward comparing full keys.
+                while j > 0 and u32[j - 1] == q:
+                    j -= 1
+                while j < total and u32[j] == q:
+                    if keybuf[j * key_len:(j + 1) * key_len] == k:
+                        marker = int(pos[j])
+                        break
+                    j += 1
+                out[k] = marker
 
+    @traced("table.perkey")
     def _perkey_resolve(self, ks: Keyspace, work, out, use_bloom) -> None:
         """Per-key path: row lock + (bloom +) point lookup.  The batch
         entry points pass ``use_bloom=False`` — their filtering already

@@ -16,6 +16,8 @@ from typing import Optional
 
 import msgpack
 
+from repro.tracing import span
+
 from .faults import DEFAULT_IO, IoBackend
 from .large_table import CellState, LargeTable
 from .util import Metrics, crc32
@@ -139,7 +141,8 @@ class SnapshotThread:
     def _loop(self) -> None:
         while not self._stop.wait(self.interval):
             try:
-                self.db.snapshot_now(flush_threshold=0)
+                with span("bg.snapshot"):
+                    self.db.snapshot_now(flush_threshold=0)
             except Exception:  # pragma: no cover
                 import traceback
                 traceback.print_exc()

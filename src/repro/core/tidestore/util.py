@@ -109,6 +109,10 @@ class Metrics:
     bloom_filters_persisted: int = 0   # filters written next to index blobs
     bloom_filters_loaded: int = 0      # persisted filters loaded on reopen
     fused_bloom_probes: int = 0        # fused ragged probes (1 per batch)
+    lookup_dispatches: int = 0         # jitted optimistic_lookup calls
+    bloom_dispatches: int = 0          # jitted fused Bloom probe calls
+    h2d_bytes: int = 0                 # padded arrays copied to the device
+    d2h_bytes: int = 0                 # results copied back from the device
     parallel_copy_subruns: int = 0     # pwritev sub-runs issued by append_many
     cache_hits: int = 0
     cache_misses: int = 0

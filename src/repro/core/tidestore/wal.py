@@ -57,6 +57,8 @@ from typing import Callable, Iterator, Optional
 
 import numpy as np
 
+from repro.tracing import traced
+
 from .faults import (DEFAULT_IO, CorruptionError, IoBackend, TornRecordError,
                      UnrepairedHoleError, WalHoleError)
 from .util import Metrics, PositionTracker, crc32, crc32_parts
@@ -570,6 +572,7 @@ class Wal:
                          bytes_written_app=app_bytes if app_bytes is not None else rec_len)
         return pos
 
+    @traced("wal.append_many")
     def append_many(self, records: list[tuple[int, bytes]], epoch: int = 0,
                     app_bytes: Optional[int] = None,
                     epochs: Optional[list[int]] = None,
@@ -934,6 +937,7 @@ class Wal:
             raise CorruptionError(f"WAL record at {pos} failed CRC", pos)
         return rtype, payload
 
+    @traced("wal.value_read")
     def read_records_batch(self, positions, *, max_run_bytes: int = 1 << 20,
                            max_gap: int = 32 * 1024) -> dict:
         """Coalesced positional reads for a batch of record positions.

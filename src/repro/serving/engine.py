@@ -38,6 +38,7 @@ from repro.core.tidestore.system import SYSTEM_KEYSPACE
 from repro.models import serve as serve_mod
 from repro.models.base import ModelConfig
 from repro.serving.admission import AdmissionController, Overloaded
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -66,8 +67,8 @@ class KvRead:
     found: Optional[bool] = None
     done: bool = False
     error: Optional[BaseException] = None
-    t_submit: float = dataclasses.field(default_factory=time.time)
-    t_done: Optional[float] = None
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter)
+    t_done: Optional[float] = None      # time.perf_counter(), as t_submit
 
     def result(self):
         if self.error is not None:
@@ -88,8 +89,8 @@ class KvWrite:
     pos: Optional[int] = None
     done: bool = False
     error: Optional[BaseException] = None
-    t_submit: float = dataclasses.field(default_factory=time.time)
-    t_done: Optional[float] = None
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter)
+    t_done: Optional[float] = None      # time.perf_counter(), as t_submit
 
     def result(self):
         if self.error is not None:
@@ -169,6 +170,12 @@ class KvBatchServer:
             except Exception:       # engine without a __system keyspace
                 self._reserved_ks = None
         self.batches_served = 0
+        # Steps that took requests, and their wall and serving-thread CPU
+        # seconds: the difference is time off the CPU (the GIL, I/O, the
+        # device).
+        self.steps_served = 0
+        self.step_wall_s = 0.0
+        self.step_cpu_s = 0.0
         self.keys_served = 0
         self.exists_served = 0
         self.writes_served = 0
@@ -271,6 +278,18 @@ class KvBatchServer:
             self._maybe_scrub()          # ... and verify integrity in lulls
             self._maybe_recover()        # ... and probe a degraded engine
             return 0
+        t_wall, t_cpu = time.perf_counter(), time.thread_time()
+        with span("serve.step"):
+            with span("serve.schedule"):
+                stages = self._schedule(take)
+            served = self._serve_stages(stages)
+        self.steps_served += 1
+        self.step_wall_s += time.perf_counter() - t_wall
+        self.step_cpu_s += time.thread_time() - t_cpu
+        return served
+
+    def _schedule(self, take: list) -> list:
+        """Drained requests → ordered (is_write, ops, keys) stages."""
         # Conflict keys normalize the keyspace (engines accept an index or
         # a name for the same keyspace; both spellings must collide here).
         norm = getattr(self.db, "_ks_id", lambda ks: ks)
@@ -294,17 +313,21 @@ class KvBatchServer:
                     break
             else:
                 stages.append((is_write, [r], {rk}))
+        return stages
+
+    def _serve_stages(self, stages: list) -> int:
         served = 0
         for is_write, ops, _ in stages:
             try:
-                served += (self._serve_writes(ops) if is_write
-                           else self._serve_reads(ops))
+                with span("serve.writes" if is_write else "serve.reads"):
+                    served += (self._serve_writes(ops) if is_write
+                               else self._serve_reads(ops))
             except Exception as exc:
                 # A failing stage (I/O error, engine validation) must not
                 # poison the loop: every not-yet-served request in it
                 # completes with the error attached (result() re-raises to
                 # that submitter), the other stages still serve.
-                now = time.time()
+                now = time.perf_counter()
                 for r in ops:
                     if not r.done:
                         r.error, r.done, r.t_done = exc, True, now
@@ -374,7 +397,7 @@ class KvBatchServer:
                 for r, f in zip(group, flags):
                     r.found = f
                 self.exists_served += len(group)
-            now = time.time()
+            now = time.perf_counter()
             for r in group:
                 r.done, r.t_done = True, now
             self.batches_served += 1
@@ -423,7 +446,7 @@ class KvBatchServer:
                                             keyspace=ks, opts=self.write_opts)
                 for r, pos in zip(group, positions):
                     r.pos = pos
-        now = time.time()
+        now = time.perf_counter()
         for r in reqs:
             r.done, r.t_done = True, now
         self.batches_served += 1
@@ -471,7 +494,7 @@ class KvBatchServer:
             dropped = list(self.queue)
             self.queue.clear()
         exc = RuntimeError("KvBatchServer closed before serving request")
-        now = time.time()
+        now = time.perf_counter()
         for r in dropped:
             r.error, r.done, r.t_done = exc, True, now
         if self.admission is not None:
@@ -483,6 +506,9 @@ class KvBatchServer:
         with self._lock:                 # consistent vs concurrent submitters
             queued = len(self.queue)
         return {"batches_served": self.batches_served,
+                "steps_served": self.steps_served,
+                "step_wall_s": self.step_wall_s,
+                "step_cpu_s": self.step_cpu_s,
                 "keys_served": self.keys_served,
                 "exists_served": self.exists_served,
                 "writes_served": self.writes_served,

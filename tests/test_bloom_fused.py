@@ -95,7 +95,7 @@ class TestProbeCellsParity:
         cells, added = build_cells(spec)
         queries, groups = ragged_queries(added, 70)
         h1, h2 = key_hashes_many(queries)
-        got = probe_cells(cells, h1, h2, groups, use_kernel=use_kernel)
+        got, _ = probe_cells(cells, h1, h2, groups, use_kernel=use_kernel)
         want = np.zeros(len(queries), dtype=bool)
         for ci, g in enumerate(groups):
             for qi in g:
@@ -109,16 +109,17 @@ class TestProbeCellsParity:
         cells, added = build_cells([(50, 30)])
         queries = added[0] + keys_n(10, "u")
         h1, h2 = key_hashes_many(queries)
-        got = probe_cells(cells, h1, h2, [np.arange(len(added[0]))])
+        got, _ = probe_cells(cells, h1, h2, [np.arange(len(added[0]))])
         assert got[:30].all() and not got[30:].any()
 
     def test_empty_inputs(self):
         cells, _ = build_cells([(10, 5)])
         assert probe_cells(cells, np.zeros(0, np.uint32),
-                           np.zeros(0, np.uint32), [[]]).shape == (0,)
-        assert not probe_cells([], np.uint32([1]), np.uint32([1]), []).any()
+                           np.zeros(0, np.uint32), [[]])[0].shape == (0,)
+        assert not probe_cells([], np.uint32([1]), np.uint32([1]),
+                               [])[0].any()
         assert not probe_cells([None], np.uint32([1]), np.uint32([1]),
-                               [[0]]).any()
+                               [[0]])[0].any()
 
     @pytest.mark.parametrize("q", [63, 64, 65, 127, 128, 129])
     def test_pow2_padding_boundaries(self, q):
@@ -150,7 +151,7 @@ class TestProbeCellsParity:
         if not queries:
             return
         h1, h2 = key_hashes_many(queries)
-        got = probe_cells(cells, h1, h2, groups, use_kernel=use_kernel)
+        got, _ = probe_cells(cells, h1, h2, groups, use_kernel=use_kernel)
         for ci, g in enumerate(groups):
             for qi in g:
                 assert got[qi] == cells[ci].might_contain(queries[qi])
@@ -161,7 +162,6 @@ class TestDispatchBudget:
         """However many cells the batch touches: ONE fused kernel dispatch
         (blob memo disabled so the Bloom gate stays live; 8 cells × 1024
         queries crosses the per-cell-scaled kernel threshold)."""
-        from repro.kernels.bloom_check import ops as bloom_ops
         cfg = small_cfg(blob_cache_bytes=0)
         with TideDB(tmpdir, cfg) as db:
             present = keys_n(512, "p")
@@ -169,31 +169,30 @@ class TestDispatchBudget:
             db.snapshot_now(flush_threshold=1)     # cells → UNLOADED
             batch = present + keys_n(512, "miss")
             db.multi_exists(batch)                 # warm the jit shapes
-            before_k = bloom_ops.ragged_dispatch_count
+            before_k = db.stats()["bloom_dispatches"]
             before_p = db.metrics.fused_bloom_probes
             got = db.multi_exists(batch)
-            assert bloom_ops.ragged_dispatch_count - before_k == 1
+            assert db.stats()["bloom_dispatches"] - before_k == 1
             assert db.metrics.fused_bloom_probes - before_p == 1
             assert got == [db.exists(k) for k in batch]
             # below the scaled threshold: still one fused probe, but the
             # identical numpy pass — zero kernel dispatches
-            before_k = bloom_ops.ragged_dispatch_count
+            before_k = db.stats()["bloom_dispatches"]
             before_p = db.metrics.fused_bloom_probes
             small = db.multi_exists(batch[:96])
-            assert bloom_ops.ragged_dispatch_count == before_k
+            assert db.stats()["bloom_dispatches"] == before_k
             assert db.metrics.fused_bloom_probes - before_p == 1
             assert small == got[:96]
 
     def test_kernel_off_routes_numpy_and_agrees(self, tmpdir):
-        from repro.kernels.bloom_check import ops as bloom_ops
         cfg = small_cfg(blob_cache_bytes=0, batched_kernels=False)
         with TideDB(tmpdir, cfg) as db:
             present = keys_n(512, "p")
             db.put_many([(k, b"v" * 32) for k in present])
             db.snapshot_now(flush_threshold=1)
-            before = bloom_ops.ragged_dispatch_count
+            before = db.stats()["bloom_dispatches"]
             got = db.multi_exists(present + keys_n(512, "miss"))
-            assert bloom_ops.ragged_dispatch_count == before
+            assert db.stats()["bloom_dispatches"] == before
             assert got == [True] * 512 + [False] * 512
 
 

@@ -157,7 +157,7 @@ class TestOptimisticLookup:
         queries = np.concatenate([
             keys[:64], np.arange(2**31, 2**31 + 64, dtype=np.uint32),
             rng.integers(0, 2**32, 64, dtype=np.uint32)]).astype(np.uint32)
-        idx, found, unresolved = lookup_indices_batch(
+        idx, found, unresolved, _ = lookup_indices_batch(
             queries, keys, window=128, max_iters=2)
         assert unresolved > 0                 # the host search ran
         ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
@@ -176,7 +176,8 @@ class TestOptimisticLookup:
                                                dtype=np.uint32)
         ]).astype(np.uint32)
         pos = np.arange(len(keys), dtype=np.uint32) + 7
-        idx, found, _ = lookup_indices_batch(queries, keys, window=window)
+        idx, found, _, _ = lookup_indices_batch(queries, keys,
+                                                window=window)
         got = np.where(found, pos[np.clip(idx, 0, len(keys) - 1)], 0)
         ridx, rfound = optimistic_lookup_ref(jnp.asarray(queries),
                                              jnp.asarray(keys))
@@ -206,8 +207,8 @@ class TestOptimisticLookup:
                                       np.asarray(ridx)[resolved])
         assert found[resolved].all()
         # The host entry pads the same column itself.
-        _, bfound, unresolved = lookup_indices_batch(queries, keys,
-                                                     window=512)
+        _, bfound, unresolved, _ = lookup_indices_batch(queries, keys,
+                                                        window=512)
         assert unresolved <= 0.01 * len(queries) and bfound.all()
 
     def test_segments_resolve_a_gapped_concatenation(self):
@@ -228,7 +229,7 @@ class TestOptimisticLookup:
         qcell = (queries.astype(np.uint64) * n_cells) >> 32
         base = starts[qcell]
         frac = (queries.astype(np.uint64) * n_cells).astype(np.uint32)
-        idx, found, unresolved = lookup_indices_batch(
+        idx, found, unresolved, _ = lookup_indices_batch(
             queries, kept, segments=(base, ends[qcell] - base, frac),
             window=512)
         assert unresolved <= 0.01 * len(queries)
