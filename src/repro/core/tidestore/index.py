@@ -5,12 +5,13 @@ Two formats, exactly as benchmarked in the paper:
 - **Optimistic index**: a flat sorted array of fixed-size entries
   (``key_len``-byte key + 8-byte WAL position; 40 bytes for 32-byte keys).
   No header, no directory.  A lookup treats the key as an integer, computes
-  its fractional position in the keyspace, multiplies by the file size to get
-  an estimated byte offset, reads a window of W entries there, and
-  binary-searches.  If the target is outside the window's key range the
-  window shifts toward the right end; with uniform keys this converges in
-  1–3 iterations (order statistics of U(0,1) samples: the i-th key
-  concentrates around i/N with σ ≈ √N, far below one window).
+  its fractional position in the blob's own key range (a uniform cell's
+  slice of the keyspace; the whole keyspace only when the blob spans it),
+  multiplies by the entry count to get an estimated offset, reads a window
+  of W entries there, and binary-searches.  If the target is outside the
+  window's key range the window shifts toward that end; with uniform keys
+  the first window holds it (order statistics of U(0,1) samples: the i-th
+  key concentrates around i/N with σ ≤ √N/2, far below one window).
   A bounded linear-probe phase falls back to bisection so that adversarial
   (non-uniform) keys still terminate in O(log N) window reads.
 
@@ -129,8 +130,12 @@ def build_sorted_blob(entries: dict[bytes, int], key_len: int) -> tuple[bytes, i
     return out.tobytes(), n
 
 
-def _key_fraction(key: bytes) -> float:
-    return int.from_bytes(key[:8].ljust(8, b"\x00"), "big") / float(1 << 64)
+def _key_fraction(key: bytes, lo: int = 0, hi: int = 1 << 64) -> float:
+    """The key's position in the key range [lo, hi) of big-endian u64 key
+    prefixes, clamped to [0, 1]: a key outside the range (a predecessor
+    probe past a cell's end) lands at that end."""
+    p = int.from_bytes(key[:8].ljust(8, b"\x00"), "big")
+    return min(max(p - lo, 0), hi - lo) / (hi - lo)
 
 
 # --------------------------------------------------------------- optimistic
@@ -147,17 +152,25 @@ def load_optimistic(pread: Callable[[int, int], bytes], count: int,
 
 
 class OptimisticLookup:
-    """Windowed interpolation search over a serialized optimistic index."""
+    """Windowed interpolation search over a serialized optimistic index.
+
+    The first window is centred on the key's fraction of the blob's own
+    key range, ``segment`` = [lo, hi) as big-endian u64 key prefixes; a
+    uniform cell passes its slice of the keyspace.  Without a segment the
+    range is the whole keyspace, which is right only for a blob that spans
+    it."""
 
     def __init__(self, pread: Callable[[int, int], bytes], count: int,
                  key_len: int, window_entries: int = 800,
-                 linear_probes: int = 4, metrics: Optional[Metrics] = None):
+                 linear_probes: int = 4, metrics: Optional[Metrics] = None,
+                 segment: Optional[tuple[int, int]] = None):
         self.pread = pread
         self.count = count
         self.key_len = key_len
         self.window = max(8, window_entries)
         self.linear_probes = linear_probes
         self.metrics = metrics
+        self.segment = segment or (0, 1 << 64)
         self.esz = entry_size(key_len)
 
     def _read_window(self, start: int, n: int):
@@ -174,7 +187,7 @@ class OptimisticLookup:
             return b"", np.zeros((0, 1), dtype=">u8"), np.zeros(0, "<u8"), 0, 0
         words = _key_words(key, self.key_len)
         lo, hi = 0, n                       # bounds on the insertion point
-        est = int(_key_fraction(key) * n)   # §4.2: fractional position estimate
+        est = int(_key_fraction(key, *self.segment) * n)   # §4.2
         iters = 0
         while True:
             start = min(max(est - w // 2, lo), max(hi - w, lo))
@@ -198,7 +211,8 @@ class OptimisticLookup:
                 break                       # key falls exactly between windows
             est = min(max(est, lo), max(hi - 1, lo))
         if self.metrics:
-            self.metrics.add(index_lookups=1, index_lookup_iterations=iters)
+            self.metrics.add(index_lookups=1, index_lookup_iterations=iters,
+                             windowed_lookups=1, windowed_reads=iters)
         return buf, cols, pos, start, iters
 
     def lookup(self, key: bytes) -> tuple[Optional[int], int]:

@@ -115,6 +115,16 @@ class Keyspace:
         x = h * self.cfg.n_cells
         return x >> 32, x & 0xFFFFFFFF
 
+    def key_range(self, cid) -> Optional[tuple[int, int]]:
+        """A uniform cell's key range [lo, hi) as big-endian u64 key
+        prefixes: the u32 prefixes h with (h·n_cells) >> 32 == cid, shifted
+        up by 32.  None for a prefix keyspace."""
+        if self.cfg.distribution != "uniform":
+            return None
+        n = self.cfg.n_cells
+        return (((cid << 32) + n - 1) // n << 32,
+                (((cid + 1) << 32) + n - 1) // n << 32)
+
     def cell_id_for_key(self, key: bytes) -> object:
         if self.cfg.distribution == "uniform":
             h = int.from_bytes(key[:4].ljust(4, b"\x00"), "big")
@@ -423,14 +433,22 @@ class LargeTable:
                                 if not is_tombstone(p)])
                 cell.bloom = bloom
 
+    def _index_reader(self, ks: Keyspace, cell: Cell):
+        """The per-key reader of the cell's on-disk index; the optimistic
+        one places its first window from the key's position inside the
+        cell's own key range (a prefix keyspace's cells have none to give,
+        so it falls back to the whole keyspace)."""
+        _, lookup_cls, _ = FORMATS[ks.cfg.index_format]
+        return lookup_cls(self._bounded_pread(cell.disk_pos, cell.disk_len),
+                          cell.disk_count, ks.cfg.key_len,
+                          window_entries=ks.cfg.window_entries,
+                          segment=ks.key_range(cell.cell_id),
+                          metrics=self.metrics)
+
     def _disk_lookup(self, ks: Keyspace, cell: Cell, key: bytes) -> Optional[int]:
         if not cell.has_disk():
             return None
-        _, lookup_cls, _ = FORMATS[ks.cfg.index_format]
-        pread = self._bounded_pread(cell.disk_pos, cell.disk_len)
-        lk = lookup_cls(pread, cell.disk_count, ks.cfg.key_len,
-                        window_entries=ks.cfg.window_entries, metrics=self.metrics)
-        pos, _ = lk.lookup(key)
+        pos, _ = self._index_reader(ks, cell).lookup(key)
         return pos
 
     def _position_locked(self, ks: Keyspace, cell: Cell,
@@ -884,12 +902,7 @@ class LargeTable:
             disk_arr = None
             if cell.state in (CellState.UNLOADED, CellState.DIRTY_UNLOADED) \
                     and cell.has_disk():
-                _, lookup_cls, _ = FORMATS[ks.cfg.index_format]
-                pread = self._bounded_pread(cell.disk_pos, cell.disk_len)
-                lk = lookup_cls(pread, cell.disk_count, ks.cfg.key_len,
-                                window_entries=ks.cfg.window_entries,
-                                metrics=self.metrics)
-                disk_arr = lk
+                disk_arr = self._index_reader(ks, cell)
             probe = key
             while True:
                 best_key, best_marker = None, None

@@ -96,6 +96,8 @@ class Metrics:
     index_flushes: int = 0
     index_lookups: int = 0
     index_lookup_iterations: int = 0
+    windowed_lookups: int = 0          # per-key windowed index searches
+    windowed_reads: int = 0            # ... and the windows they read
     batched_append_runs: int = 0       # coalesced pwrite runs (append_many)
     batched_blob_reads: int = 0        # whole-cell index reads (multi_get)
     batched_kernel_lookups: int = 0    # queries resolved via Pallas kernel
