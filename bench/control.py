@@ -7,7 +7,10 @@ visible to every later read at once.  ``ControlEngine`` answers from the
 loaded records alone and breaks both in the way a shortcut would:
 
 - a get returns the value as loaded: writes are acknowledged but never
-  seen (a stale answer);
+  seen (a stale answer); and it finds the key by its first four bytes
+  alone, the index's u32 key column, without the full-key check: where
+  two loaded keys share those bytes it returns the value of the smaller
+  (a wrong answer; about 116 pairs among 1 M uniform keys);
 - an exists is answered by a Bloom filter of the loaded keys alone, with
   the store's filter parameters (10 bits per key rounded up to a power of
   two, 7 probes), so its false positives stand (an approximate answer);
@@ -74,18 +77,22 @@ class BloomOnly:
 class ControlEngine:
     """The reference in the store's place with the guarantee broken; every
     other attribute forwards to the store, so the server treats it as the
-    engine it replaces."""
+    engine it replaces.  ``prefix_bytes`` is the width of the key prefix
+    a get is found by."""
 
-    def __init__(self, db, data):
+    def __init__(self, db, data, prefix_bytes: int = 4):
         self._db = db
-        self._loaded = dict(zip(data.keys, data.values))
+        self._p = prefix_bytes
+        self._by_prefix: dict = {}
+        for k, v in sorted(zip(data.keys, data.values)):
+            self._by_prefix.setdefault(k[:prefix_bytes], v)
         self._bloom = BloomOnly(data.keys)
 
     def __getattr__(self, name):
         return getattr(self._db, name)
 
     def multi_get(self, keys, keyspace=0, opts=None):
-        return [self._loaded.get(k) for k in keys]
+        return [self._by_prefix.get(k[:self._p]) for k in keys]
 
     def multi_exists(self, keys, keyspace=0, opts=None):
         return self._bloom.might_contain(list(keys)).tolist()

@@ -27,6 +27,7 @@ from . import trace as trace_mod
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOAD_BATCH = 4096
+READBACK_LOADED = 65536     # loaded records read back where epochs retire
 _PCT = re.compile(r"^([a-z]+)_p(\d+)_ms$")
 _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                    "/jax/core/compile/jaxpr_trace_duration")
@@ -86,10 +87,19 @@ def load_reader(metric: str, root: str = ROOT):
 
 # -------------------------------------------------------------------- store
 def store_config(config: dict):
-    from repro.core.tidestore import DbConfig, KeyspaceConfig
+    """The store's ``DbConfig``; the mappings ``wal``, ``index_wal`` and
+    ``prune`` of the configuration's ``store`` become a ``WalConfig`` each
+    and a ``PruneOptions``."""
+    from repro.core.tidestore import (DbConfig, KeyspaceConfig, PruneOptions,
+                                      WalConfig)
     ks = KeyspaceConfig("default", key_len=config["key_bytes"],
                         **config["keyspace"])
-    return DbConfig(keyspaces=[ks], **config["store"])
+    store = dict(config["store"])
+    for key, cls in (("wal", WalConfig), ("index_wal", WalConfig),
+                     ("prune", PruneOptions)):
+        if isinstance(store.get(key), dict):
+            store[key] = cls(**store[key])
+    return DbConfig(keyspaces=[ks], **store)
 
 
 def load_store(path: str, config: dict, data: traffic.Dataset):
@@ -121,10 +131,18 @@ class ClosedLoop:
     """Keeps ``outstanding`` requests in the server's queue: before each
     ``step()`` it submits as many as the last step completed.  Op kinds,
     keys and values come pre-drawn from the sequence, so its work per
-    request is one ``submit_*`` call and one clock read."""
+    request is one ``submit_*`` call and one clock read.
 
-    def __init__(self, srv, seq: traffic.Sequence, outstanding: int):
+    With ``epoch_requests``, each top-up first sets the server's write
+    epoch to 1 + completed // epoch_requests: a step serves one whole
+    block, so request i writes with epoch 1 + i // epoch_requests.  A step
+    that serves anything else counts in ``misaligned``."""
+
+    def __init__(self, srv, seq: traffic.Sequence, outstanding: int,
+                 epoch_requests=None):
         self.srv, self.seq, self.outstanding = srv, seq, outstanding
+        self.epoch_requests = epoch_requests
+        self.misaligned = 0
         self.op = seq.op.tolist()
         self.reqs: list = []
         self.t_submit: list = []
@@ -137,6 +155,10 @@ class ClosedLoop:
         t0 = time.perf_counter()
         srv, seq, op, reqs, ts = (self.srv, self.seq, self.op, self.reqs,
                                   self.t_submit)
+        if self.epoch_requests:
+            from repro.core.tidestore import WriteOptions
+            srv.write_opts = WriteOptions(
+                epoch=1 + self.completed // self.epoch_requests)
         submit = (srv.submit_get, srv.submit_exists)
         n = len(seq)
         for _ in range(self.outstanding - (len(reqs) - self.completed)):
@@ -151,7 +173,10 @@ class ClosedLoop:
         self.topup_ends.append(t1)
 
     def step(self) -> None:
-        self.completed += self.srv.step()
+        served = self.srv.step()
+        if self.epoch_requests:
+            self.misaligned += served != self.outstanding
+        self.completed += served
         self.ends.append((self.completed, time.perf_counter()))
         # The served requests stay referenced for the check after the
         # window; frozen, they stay out of the collections that later
@@ -280,15 +305,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
         served = engine_wrap(db, data) if engine_wrap else db
         if traced:
             served = TracedEngine(served)
-        srv = KvBatchServer(served, max_batch=wl["outstanding"])
-        loop = ClosedLoop(srv, seq, wl["outstanding"])
+        srv = KvBatchServer(served, max_batch=wl["outstanding"],
+                            prune_opts=store_config(cfg).prune)
+        loop = ClosedLoop(srv, seq, wl["outstanding"],
+                          traffic.epoch_requests(cfg, wl))
         try:
             result = _serve(cell, loop, db, seconds, traced, readers,
                             t_process, log, path)
+            if srv.prune_opts is not None:
+                _finish_reclamation(srv, db, log)
         finally:
             srv.close()
             db.close()
-        checks = _check(cfg, seq, data, loop, path, log)
+        checks = _check(cfg, seq, data, loop, path, log, seed)
     finally:
         shutil.rmtree(path, ignore_errors=True)
         gc.unfreeze()
@@ -425,14 +454,30 @@ def _serve(cell, loop, db, seconds, traced, readers, t_process, log,
     return result
 
 
-def _check(cfg, seq, data, loop, path, log) -> dict:
+def _finish_reclamation(srv, db, log, limit: int = 100_000) -> None:
+    """Serves idle steps, which run the server's reclamation slice, until
+    one starts with no relocation pass in flight: that slice drops every
+    segment below the final floor (``reference`` module doc)."""
+    rel = getattr(db, "relocator", None)
+    for steps in range(1, limit + 1):
+        scanning = rel is not None and rel.scanning
+        srv.step()
+        if not scanning:
+            break
+    log(f"bench: {steps} idle steps after the window finished reclamation")
+
+
+def _check(cfg, seq, data, loop, path, log, seed) -> dict:
     """The comparison with the reference, after the window and after the
     store is closed: every answer served (warm-up and window), then every
-    key written, read back from the store reopened cold."""
+    key written, read back from the store reopened cold.  Where epochs
+    retire writes, the read-back takes the keys still retained and a
+    sample of the loaded records, and ``expired_present`` counts the keys
+    that must be gone and are not."""
     from repro.core.tidestore import TideDB
     t = time.perf_counter()
     oracle = reference.DictOracle(data.keys, data.values)
-    counts = oracle.replay(seq, loop.reqs)
+    counts = oracle.replay(seq, loop.reqs, loop.epoch_requests)
     errors = sum(r.error is not None for r in loop.reqs)
     checks = {"wrong_answers": (counts["wrong_answers"], 0),
               "unanswered": (counts["unanswered"], 0),
@@ -440,12 +485,35 @@ def _check(cfg, seq, data, loop, path, log) -> dict:
     n = len(seq)
     written = {seq.key[i % n] for i in range(len(loop.reqs))
                if seq.op[i % n] == reference.PUT}
-    if written:
-        db = TideDB(path, store_config(cfg))
+    loaded, gone = [], None
+    if loop.epoch_requests:
+        checks["epoch_misaligned"] = (loop.misaligned, 0)
+    db_cfg = store_config(cfg)
+    retain = db_cfg.prune.retain_epochs if db_cfg.prune else None
+    if loop.epoch_requests and retain is not None:
+        e = loop.epoch_requests
+        puts = int((seq.op[:e] == reference.PUT).sum())
+        seg_records = db_cfg.wal.segment_size // (cfg["key_bytes"]
+                                                  + cfg["value_bytes"])
+        floor, written, gone = oracle.retention(retain, seg_records,
+                                                max(puts, 1))
+        rng = traffic.rng_for(seed, 4)
+        take = min(len(data.keys), READBACK_LOADED)
+        loaded = [data.keys[i] for i in
+                  rng.choice(len(data.keys), take, replace=False).tolist()]
+        log(f"bench: final floor {floor}; {len(written)} keys retained, "
+            f"{len(gone)} must be gone")
+    if written or loaded or gone is not None:
+        db = TideDB(path, db_cfg)
         try:
-            checks["readback_wrong"] = (oracle.read_back(db, written), 0)
+            checks["readback_wrong"] = (
+                oracle.read_back(db, list(written) + loaded), 0)
+            if gone is not None:
+                checks["expired_present"] = (
+                    oracle.count_present(db, gone), 0)
         finally:
             db.close()
     log(f"bench: compared {len(loop.reqs)} answers and read back "
-        f"{len(written)} written keys in {time.perf_counter() - t:.3f} s")
+        f"{len(written)} written keys and {len(loaded)} loaded records in "
+        f"{time.perf_counter() - t:.3f} s")
     return checks

@@ -17,10 +17,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
+import functools  # noqa: E402
+
 from bench import control, harness  # noqa: E402
 from repro.core.tidestore import TideDB  # noqa: E402
 
-EXISTS, YCSB = "kv1k-uniform.exists", "ycsb-1k.b-zipfian"
+EXISTS, YCSB, GET = ("kv1k-uniform.exists", "ycsb-1k.b-zipfian",
+                     "kv1k-uniform.get")
 
 
 @pytest.fixture(autouse=True)
@@ -48,7 +51,7 @@ def _checks(res):
     return {k: v["value"] for k, v in res["checks"].items()}
 
 
-@pytest.mark.parametrize("name", [EXISTS, YCSB])
+@pytest.mark.parametrize("name", [EXISTS, YCSB, GET])
 def test_a_sound_run_is_correct(name):
     res, lines = _run(name)
     assert res["correct"] is True, res["checks"]
@@ -72,7 +75,7 @@ def test_writes_acknowledged_but_not_landed_fail(monkeypatch):
     assert _checks(res)["readback_wrong"] > 0
 
 
-@pytest.mark.parametrize("name", [EXISTS, YCSB])
+@pytest.mark.parametrize("name", [EXISTS, YCSB, GET])
 def test_half_of_each_batch_left_out_fails(monkeypatch, name):
     """The engine answers the first half of each batch and leaves the rest
     at their defaults."""
@@ -91,7 +94,7 @@ def test_half_of_each_batch_left_out_fails(monkeypatch, name):
     assert _checks(res)["wrong_answers"] > 0
 
 
-@pytest.mark.parametrize("name", [EXISTS, YCSB])
+@pytest.mark.parametrize("name", [EXISTS, YCSB, GET])
 def test_one_answer_altered_per_batch_fails(monkeypatch, name):
     """One answer of each batch altered where the engine produces it."""
     real_get, real_exists = TideDB.multi_get, TideDB.multi_exists
@@ -115,13 +118,17 @@ def test_one_answer_altered_per_batch_fails(monkeypatch, name):
     assert _checks(res)["wrong_answers"] > 0
 
 
-@pytest.mark.parametrize("name", [EXISTS, YCSB])
+@pytest.mark.parametrize("name", [EXISTS, YCSB, GET])
 def test_the_control_reads_not_correct(name):
     """The reference in the store's place, answering exists from a Bloom
     filter alone and reads from the loaded records alone."""
     # an exists is wrong only on a false positive (~0.07 % of absent keys
-    # at this size), so the window runs long enough for dozens of them
-    res, _ = _run(name, engine_wrap=control.ControlEngine, seconds=2.0)
+    # at this size), so the window runs long enough for dozens of them; a
+    # get of a loaded key only where another shares its prefix: 2 bytes
+    # give 4,096 keys about as many such pairs (~128) as 4 bytes give 1 M
+    # keys (~116)
+    res, _ = _run(name, seconds=2.0, engine_wrap=functools.partial(
+        control.ControlEngine, prefix_bytes=2 if name == GET else 4))
     assert res["correct"] is False
     assert _checks(res)["wrong_answers"] > 0
 

@@ -107,25 +107,27 @@ def test_a_new_cell_needs_only_new_files(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     bench = json.loads(json.dumps(BENCH))
     bench["workloads"].append({
-        "name": "kv1k-uniform.get", "config": "kv1k-uniform",
-        "traffic": "get", "chips": 1,
-        "why": "closed loop of 4096 gets of present keys, uniform"})
+        "name": "kv1k-uniform.put", "config": "kv1k-uniform",
+        "traffic": "put", "chips": 1,
+        "why": "closed loop of 4096 puts of new keys, uniform"})
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if m["name"] in ("get_p95_ms", "serving.keys_per_stage"):
-            m["workloads"].append("kv1k-uniform.get")
+        if m["name"] in ("put_p95_ms", "serving.keys_per_stage"):
+            m["workloads"].append("kv1k-uniform.put")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    (tmp_path / "bench" / "workloads" / "kv1k-uniform.get.json").write_text(
+    (tmp_path / "bench" / "workloads" / "kv1k-uniform.put.json").write_text(
         json.dumps({"config": "kv1k-uniform", "loop": "closed",
-                    "outstanding": 64, "mix": {"get": 1.0},
+                    "outstanding": 64, "mix": {"put": 1.0},
                     "keys": {"distribution": "uniform"},
-                    "absent_share": 0.0, "put_keys": "existing",
+                    "absent_share": 0.0, "put_keys": "new",
                     "sequence_blocks": 4,
                     "warmup": {"requests": 64}}))
-    cell = harness.load_cell("kv1k-uniform.get", root=str(tmp_path))
+    cell = harness.load_cell("kv1k-uniform.put", root=str(tmp_path))
     assert {m["name"] for m in cell.end_to_end} == {"setup_s", "ops_per_s",
-                                                    "get_p95_ms"}
+                                                    "put_p95_ms"}
     assert [m["name"] for m in cell.per_layer] == ["serving.keys_per_stage"]
     cell.config["records"] = 256
     data = traffic.make_dataset(cell.config, 1)
     seq = traffic.make_sequence(cell.config, cell.workload, data, 1)
-    assert len(seq) == 256 and (seq.op == traffic.OPS.index("get")).all()
+    assert (seq.op == traffic.OPS.index("put")).all()
+    assert not set(seq.key) & set(data.keys)
+    assert len(seq) == 256

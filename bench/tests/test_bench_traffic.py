@@ -11,7 +11,7 @@ sys.path.insert(0, ROOT)
 
 from bench import harness, traffic  # noqa: E402
 
-CELLS = ("kv1k-uniform.exists", "ycsb-1k.b-zipfian")
+CELLS = ("kv1k-uniform.exists", "ycsb-1k.b-zipfian", "kv1k-uniform.get")
 
 
 def _fnv_reference(val: int) -> int:

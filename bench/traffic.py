@@ -5,10 +5,16 @@ configuration file (``bench/configs/<config>.json``) and a traffic file
 (``bench/workloads/<cell>.json``): the same seed gives the same records,
 the same request sequence and the same values to write.
 
-Three streams of one seed keep the parts independent: stream 0 makes the
+Streams of one seed keep the parts independent: stream 0 makes the
 loaded records, stream 1 the request sequence (op kinds, key choices and
 the values that updates write), stream 2 the absent keys that existence
-checks and reads ask for.
+checks and reads ask for, stream 3 the choices of the ``recent`` chooser
+(reads of keys put earlier), stream 4 the sample of loaded records that
+the reference reads back where epochs retire writes.
+
+Epochs (traffic key ``epoch_requests``): request i of the closed loop,
+counted from the first warm-up request, writes with epoch
+``1 + i // epoch_requests``; loaded records are untagged (epoch 0).
 
 The YCSB request chooser is a copy of YCSB's ``ScrambledZipfianGenerator``
 (core/src/main/java/site/ycsb/generator/): a zipfian over 10^10 items
@@ -133,13 +139,66 @@ def choose_records(rng, n: int, records: int, keys: dict) -> np.ndarray:
     raise ValueError(f"unknown key distribution {dist!r}")
 
 
+def epoch_requests(cfg: dict, wl: dict):
+    """The traffic's ``epoch_requests``, or None without one.  An epoch is
+    a whole number of closed-loop blocks and the sequence a whole number
+    of epochs, so every step writes in one epoch and a cycle of the
+    sequence starts one; the ``recent`` chooser reads no further back than
+    the store retains."""
+    e = wl.get("epoch_requests")
+    keys = wl["keys"]
+    if e is None:
+        if keys["distribution"] == "recent":
+            raise ValueError("the recent chooser needs epoch_requests")
+        return None
+    n = wl["outstanding"] * wl["sequence_blocks"]
+    if e <= 0 or e % wl["outstanding"] or n % e:
+        raise ValueError(f"epoch_requests {e} must be a multiple of "
+                         f"outstanding {wl['outstanding']} and divide the "
+                         f"sequence's {n} requests")
+    if keys["distribution"] == "recent":
+        retain = (cfg["store"].get("prune") or {}).get("retain_epochs")
+        if retain is not None and keys["within_epochs"] > retain:
+            raise ValueError(f"within_epochs {keys['within_epochs']} exceeds "
+                             f"retain_epochs {retain}")
+    return e
+
+
+def recent_sources(rng, is_put: np.ndarray, reads: np.ndarray,
+                   per_epoch: int, keys: dict,
+                   records: int) -> tuple[np.ndarray, np.ndarray]:
+    """The ``recent`` chooser, for the reads of present keys ``reads``
+    (positions, ascending): each reads a loaded record with probability
+    ``loaded_share``, else a key put earlier in the same cycle of the
+    sequence within the newest ``within_epochs`` epochs of its own
+    (``per_epoch`` requests each), uniformly.  A read with no such put
+    takes a loaded record.
+
+    Returns (source, record) per read: the position of the put it reads,
+    or -1; the loaded record it reads, or -1 (neither: no record is
+    loaded, and the read asks for an absent key)."""
+    puts = np.flatnonzero(is_put)
+    first = np.maximum(0, (reads // per_epoch - keys["within_epochs"] + 1)
+                       * per_epoch)
+    lo = np.searchsorted(puts, first)
+    count = np.searchsorted(puts, reads) - lo
+    loaded = (rng.random(reads.size) < keys["loaded_share"]) | (count == 0)
+    pick = lo + (rng.random(reads.size) * count).astype(np.int64)
+    source = np.where(loaded, -1, puts[np.minimum(pick, puts.size - 1)]
+                      if puts.size else -1)
+    record = (rng.integers(0, records, reads.size) if records
+              else np.full(reads.size, -1))
+    return source, np.where(loaded, record, -1)
+
+
 # ----------------------------------------------------------------- requests
 @dataclasses.dataclass
 class Sequence:
     """A closed loop's request sequence, cycled when a window outruns it.
 
     ``op[i]`` indexes ``OPS``; ``key[i]`` is the key asked for or written;
-    ``record[i]`` its record number, or -1 for an absent or new key;
+    ``record[i]`` its record number, or -1 for a key not loaded (absent,
+    new, or put earlier in the sequence);
     ``value[i]`` is the value a put writes (None for reads)."""
     op: np.ndarray
     record: np.ndarray
@@ -174,13 +233,31 @@ def make_sequence(cfg: dict, wl: dict, data: Dataset, seed: int) -> Sequence:
                             [1.0 - wl["absent_share"], wl["absent_share"]])
     is_put = op == OPS.index("put")
     fresh = (absent == 1) | (is_put & (wl["put_keys"] == "new"))
-    record = choose_records(rng, n, cfg["records"], wl["keys"])
+    source = None
+    if wl["keys"]["distribution"] == "recent":
+        if wl["put_keys"] != "new":
+            raise ValueError("the recent chooser reads puts of new keys")
+        record = np.full(n, -1, np.int64)
+        reads = np.flatnonzero(~fresh & ~is_put)
+        src, rec = recent_sources(rng_for(seed, 3), is_put, reads,
+                                  epoch_requests(cfg, wl), wl["keys"],
+                                  cfg["records"])
+        record[reads] = rec
+        fresh[reads[(src < 0) & (rec < 0)]] = True
+        source = np.full(n, -1, np.int64)
+        source[reads] = src
+    else:
+        record = choose_records(rng, n, cfg["records"], wl["keys"])
     record[fresh] = -1
     n_fresh = int(fresh.sum())
     extra = (absent_keys(n_fresh, cfg["key_bytes"], seed, set(data.keys))
              if n_fresh else [])
     it = iter(extra)
-    keys = [data.keys[r] if r >= 0 else next(it) for r in record.tolist()]
+    keys = [data.keys[r] if r >= 0 else next(it) if f else None
+            for r, f in zip(record.tolist(), fresh.tolist())]
+    if source is not None:
+        for j in np.flatnonzero(source >= 0).tolist():
+            keys[j] = keys[source[j]]
     vb = cfg["value_bytes"]
     puts = split(rng.bytes(int(is_put.sum()) * vb), vb)
     pv = iter(puts)
