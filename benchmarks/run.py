@@ -17,7 +17,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: kv,kvbatch,kvshard,kvwrite,"
                          "kvexists,reloc,index,recovery,faults,overload,"
-                         "system,validator,kernels,roofline")
+                         "system,kernels,roofline")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
@@ -27,7 +27,7 @@ def main() -> None:
 
     from . import (faults, index_formats, kernel_bench, kv_exists,
                    kv_throughput, kv_write, overload, recovery, relocation,
-                   roofline_report, system_keyspace, validator_sim)
+                   roofline_report, system_keyspace)
 
     suites = [
         ("kv", kv_throughput.run),          # Figures 1, 6, 7, 8
@@ -41,7 +41,6 @@ def main() -> None:
         ("faults", faults.run),             # fault fuzz + scrub + degraded
         ("overload", overload.run),         # admission control loop
         ("system", system_keyspace.run),    # __system observation overhead
-        ("validator", validator_sim.run),   # §6.4 (Sui stand-in)
         ("kernels", kernel_bench.run),      # Pallas kernels
         ("roofline", roofline_report.run),  # dry-run roofline table
     ]

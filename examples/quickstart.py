@@ -31,13 +31,14 @@ def main() -> None:
         objects = db.keyspace("objects")      # bind the keyspace once
         meta = db.keyspace("meta")
 
-        # hash-keyed large values — the paper's target workload
+        # hash-keyed large values — the paper's target workload, tagged
+        # with epochs 1-5 (untagged records, epoch 0, never expire)
         for i in range(5_000):
             key = hashlib.sha256(f"object-{i}".encode()).digest()
             objects.put(key, f"payload-{i}".encode() + bytes(1024),
-                        opts=WriteOptions(epoch=i // 1000))
+                        opts=WriteOptions(epoch=1 + i // 1000))
 
-        # probe a key from epoch 4: it must survive the epoch-<3 prune below
+        # probe a key from epoch 5: it must survive the epoch-<4 prune below
         key = hashlib.sha256(b"object-4234").digest()
         print("get:", objects.get(key)[:12])
         print("exists:", objects.exists(key))
@@ -60,9 +61,9 @@ def main() -> None:
         print("batched meta:",
               meta.get(hashlib.sha256(b"tx-1-meta").digest()[:32]))
 
-        # epoch pruning: drop whole WAL segments for epochs < 3 — no bytes
+        # epoch pruning: drop whole WAL segments for epochs < 4 — no bytes
         # are relocated
-        pruned = db.prune_epochs_below(3)
+        pruned = db.prune_epochs_below(4)
         print(f"pruned {pruned} expired segments")
 
         s = db.stats()

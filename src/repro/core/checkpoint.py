@@ -5,7 +5,7 @@ paper's exact target.  Each parameter shard is one WAL value keyed by
 blake2b(param_path ‖ shard_index ‖ step); the Large Table maps keys to WAL
 positions; Control-Region snapshots + WAL-suffix replay give crash-safe
 restarts; epoch-based pruning retires old steps at segment granularity
-(epoch == training step).
+(epoch == training step + 1: the store never expires epoch 0).
 
 Topology-agnostic: values are keyed by (path, global_slice), so a restart
 may use a different mesh — shards are re-assembled from slices and
@@ -91,25 +91,25 @@ class CheckpointManager:
                     chunk = raw[part * self.chunk_bytes:
                                 (part + 1) * self.chunk_bytes]
                     self.db.put(_key("ckpt", step, pstr, part), chunk,
-                                keyspace="ckpt", epoch=step)
+                                keyspace="ckpt", epoch=step + 1)
                 manifest.append({"path": pstr, "dtype": str(buf.dtype),
                                  "shape": list(buf.shape), "parts": nparts})
             self.db.put(_key("manifest", step, "", 0),
                         json.dumps({"step": step, "leaves": manifest,
                                     "time": time.time()}).encode(),
-                        keyspace="meta", epoch=step)
+                        keyspace="meta", epoch=step + 1)
             self.db.put(_key("latest", 0, "", 0),
-                        str(step).encode(), keyspace="meta", epoch=step)
+                        str(step).encode(), keyspace="meta", epoch=step + 1)
             self.db.flush()
             self._prune(step)
 
     def _prune(self, newest_step: int) -> None:
         """Epoch pruning (§4.4): whole WAL segments whose steps all fall
-        below the retention horizon are dropped — no value is rewritten."""
-        steps = self.list_steps()
-        keep = set(sorted(steps)[-self.keep_last:])
-        horizon = min(keep) if keep else 0
-        self.db.prune_epochs_below(horizon)
+        below the oldest kept step are dropped — no value is rewritten.
+        Step s is written as epoch s + 1."""
+        keep = sorted(self.list_steps())[-self.keep_last:]
+        if keep:
+            self.db.prune_epochs_below(keep[0] + 1)
 
     # ---------------------------------------------------------------- load
     def latest_step(self) -> Optional[int]:

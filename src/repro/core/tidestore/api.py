@@ -100,13 +100,16 @@ class PruneOptions:
     - ``reclaim_fraction``: fraction of the live WAL span one full pass
       scans.
     - ``space_amp_trigger``: a non-forced pass runs only when the physical
-      span ≥ trigger × estimated live bytes.
+      span ≥ trigger × estimated live bytes (``PruneController``).
     - ``min_reclaim_bytes``: never trigger below this span (keeps tiny
       stores from churning).
     - ``retain_epochs``: keep only the newest N epochs — segments whose
       whole epoch range aged out drop for free, no bytes relocated; records
-      that aged out inside still-mixed segments are *retired* (tombstoned)
-      by the next relocation pass instead of being copied to the tail.
+      that aged out inside segments holding newer epochs are *retired*
+      (tombstoned) by the next relocation pass instead of being copied to
+      the tail.  Untagged records (epoch 0) never expire: a segment holding
+      one drops only once none of them is live, and otherwise has its aged
+      records retired in place (``Relocator.prune_epochs_below``).
       ``None`` disables the epoch trigger (explicit
       ``prune_epochs_below`` still works).
     - ``batch_records`` / ``batch_bytes``: harvest bounds per batched

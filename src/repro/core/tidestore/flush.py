@@ -107,6 +107,12 @@ class Flusher:
                     cur = merged.get(k)
                     if cur is None or real_pos(cur) < p:
                         merged[k] = p
+            # Entries into reclaimed log (segments dropped by epoch expiry,
+            # or below the GC watermark) read as absent: the blob drops
+            # them, so its count, the prune trigger's live keys, is live.
+            pos_live = self.value_wal.pos_live
+            merged = {k: p for k, p in merged.items()
+                      if pos_live(real_pos(p))}
             serialize, _, _ = FORMATS[cfg.index_format]
             blob, count = serialize(merged, cfg.key_len)
             rec_pos = self.index_wal.append(T_INDEX, blob)

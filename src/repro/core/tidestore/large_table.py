@@ -75,7 +75,7 @@ class Cell:
         self.min_dirty_pos: Optional[int] = None
         self.bloom: Optional[BloomFilter] = None
         self.flushing = False
-        self.approx_keys = 0                   # for bloom sizing
+        self.approx_keys = 0                   # live keys (live_delta)
 
     @property
     def dirty_count(self) -> int:
@@ -85,6 +85,19 @@ class Cell:
 
     def has_disk(self) -> bool:
         return self.disk_pos is not None and self.disk_count > 0
+
+    def live_delta(self, cur: Optional[int], marker: int) -> int:
+        """Change in ``approx_keys`` when ``marker`` replaces ``cur``, the
+        key's marker in the buffer (None: not buffered; in an unloaded cell
+        the key may then sit in the flushed blob, so a put counts as new
+        and a delete as removing a live key).  A flush resets the count to
+        the blob's live entries."""
+        live = not is_tombstone(marker)
+        if cur is not None:
+            return live - (not is_tombstone(cur))
+        if live:
+            return 1
+        return -(self.state in (CellState.UNLOADED, CellState.DIRTY_UNLOADED))
 
 
 class Keyspace:
@@ -215,12 +228,12 @@ class LargeTable:
                 return False
             if cur is None:
                 self._bump_mem(1)
+            cell.approx_keys += cell.live_delta(cur, pos_marker)
             cell.mem[key] = pos_marker
             p = real_pos(pos_marker)
             if cell.min_dirty_pos is None or p < cell.min_dirty_pos:
                 cell.min_dirty_pos = p
             if not is_tombstone(pos_marker):
-                cell.approx_keys += 0 if cur is not None else 1
                 if cell.bloom is not None:
                     cell.bloom.add(key)
             if cell.state == CellState.EMPTY:
@@ -264,15 +277,13 @@ class LargeTable:
                         continue
                     if cur is None:
                         mem_delta += 1
+                    cell.approx_keys += cell.live_delta(cur, marker)
                     cell.mem[key] = marker
                     p = real_pos(marker)
                     if cell.min_dirty_pos is None or p < cell.min_dirty_pos:
                         cell.min_dirty_pos = p
-                    if not is_tombstone(marker):
-                        if cur is None:
-                            cell.approx_keys += 1
-                        if cell.bloom is not None:
-                            bloom_keys.append(key)
+                    if not is_tombstone(marker) and cell.bloom is not None:
+                        bloom_keys.append(key)
                     cell_changed += 1
                 if cell_changed:
                     if bloom_keys:
@@ -306,6 +317,7 @@ class LargeTable:
                 return False
             if cell.mem.get(key) is None:
                 self._bump_mem(1)
+            cell.approx_keys += cell.live_delta(cur, new_marker)
             cell.mem[key] = new_marker
             p = real_pos(new_marker)
             if cell.min_dirty_pos is None or p < cell.min_dirty_pos:
@@ -349,6 +361,7 @@ class LargeTable:
                         continue
                     if cell.mem.get(key) is None:
                         mem_delta += 1
+                    cell.approx_keys += cell.live_delta(cur, new_marker)
                     cell.mem[key] = new_marker
                     p = real_pos(new_marker)
                     if cell.min_dirty_pos is None or p < cell.min_dirty_pos:

@@ -26,6 +26,9 @@ Two strategies, as in the paper:
 Plus the blockchain-style fast path: **epoch pruning** drops whole segments
 whose epoch range has expired without relocating a single byte — including
 segments in the *middle* of the live span (``Wal.drop_segments``).
+Untagged records (epoch 0) never expire: a segment that holds one beside
+expired tagged records drops only once none of its untagged records is
+live, and otherwise has its expired records retired in place.
 
 ``PruneController`` owns the trigger policy (space-amplification threshold
 + epoch expiry) and exposes three grains: a forced full pass (explicit
@@ -38,6 +41,8 @@ from __future__ import annotations
 import threading
 from enum import Enum
 from typing import Callable, Optional
+
+from repro.tracing import span
 
 from .api import PruneOptions
 from .index import TOMB_FLAG, is_tombstone, real_pos
@@ -55,6 +60,15 @@ class Decision(Enum):
 
 # filter(key, value_or_None, epoch) -> Decision
 RelocationFilter = Callable[[bytes, Optional[bytes], int], Decision]
+
+
+def expiry_filter(floor: int) -> RelocationFilter:
+    """Records whose epoch aged out (0 < epoch < ``floor``) are REMOVEd
+    (retired) instead of kept.  Untagged records (epoch 0) always
+    survive."""
+    def filt(key: bytes, value: Optional[bytes], epoch: int) -> Decision:
+        return Decision.REMOVE if 0 < epoch < floor else Decision.KEEP
+    return filt
 
 
 class Relocator:
@@ -78,10 +92,24 @@ class Relocator:
         # Stats of the most recent *completed* pass; the PruneController's
         # live-bytes estimator reads the live fraction from here.
         self.last_pass: dict = {}
+        # Mixed segments whose expired records were retired in place and
+        # that stay for their live untagged records (prune_epochs_below),
+        # and the newest floor expiry was asked for: mixed segments below
+        # it that are not swept yet are reclamation still in flight.
+        self._swept: set[int] = set()
+        self._expire_floor = 0
+
+    @property
+    def in_pass(self) -> bool:
+        """True while a relocation pass is in flight."""
+        return self._scan_pos is not None
 
     @property
     def scanning(self) -> bool:
-        return self._scan_pos is not None
+        """True while reclamation is in flight: a relocation pass, or
+        mixed segments below the newest floor expiry was due at
+        (``note_expire_floor``) still to sweep."""
+        return self.in_pass or bool(self._unswept(self._expire_floor))
 
     # ------------------------------------------------------------ strategies
     def relocate_wal_based(self, cutoff: Optional[int] = None,
@@ -367,14 +395,83 @@ class Relocator:
         """Drop whole WAL segments whose epoch range expired (§4.4 /
         blockchain pruning) — mid-log segments included.  Zero bytes
         relocated; reads of pruned positions resolve to absent via
-        ``Wal.pos_live``."""
-        segs = self.wal.segments_expired_below_epoch(epoch)
-        if not segs:
-            return 0
-        dropped = self.wal.drop_segments(segs)
-        if dropped:
-            self.metrics.add(segments_pruned=dropped)
-        return dropped
+        ``Wal.pos_live``.
+
+        Untagged records (epoch 0) never expire.  A segment that holds
+        untagged records beside tagged ones that all expired is read once
+        (swept): it drops whole when none of its untagged records is live
+        (the index points elsewhere), and otherwise stays with its expired
+        records retired in place, as a relocation pass's expiry filter
+        would retire them.  Sweeps every such segment; ``expire_step``
+        sweeps one.  Returns segments dropped."""
+        return self._expire(epoch, None)[0]
+
+    def expire_step(self, epoch: int) -> int:
+        """``prune_epochs_below`` bounded for a served slice: drops the
+        expired segments of tagged records and sweeps at most one mixed
+        segment, leaving the rest to later slices (``scanning`` stays True
+        until none is left).  Returns the records the sweep read (0: none
+        was swept)."""
+        return self._expire(epoch, 1)[1]
+
+    def _expire(self, epoch: int,
+                max_sweeps: Optional[int]) -> tuple[int, int]:
+        """(segments dropped, records swept) below ``epoch``."""
+        with self._lock, span("prune.expire"):
+            self.note_expire_floor(epoch)
+            segs = self.wal.segments_expired_below_epoch(epoch)
+            read = 0
+            for seg in self._unswept(epoch)[:max_sweeps]:
+                drop, n = self._sweep_mixed(seg, epoch)
+                read += n
+                if drop:
+                    segs.append(seg)
+            if not segs:
+                return 0, read
+            dropped = self.wal.drop_segments(segs)
+            if dropped:
+                self.metrics.add(segments_pruned=dropped)
+            return dropped, read
+
+    def note_expire_floor(self, epoch: int) -> None:
+        """Records that expiry is due below ``epoch``: mixed segments below
+        it count as reclamation in flight (``scanning``) until swept, also
+        while a relocation pass defers the sweep."""
+        self._expire_floor = max(self._expire_floor, epoch)
+
+    def _unswept(self, floor: int) -> list[int]:
+        """Mixed segments below ``floor`` not swept yet."""
+        if floor <= 0:
+            return []
+        return [seg for seg in self.wal.segments_mixed_below_epoch(floor)
+                if seg not in self._swept]
+
+    def _sweep_mixed(self, seg: int, floor: int) -> tuple[bool, int]:
+        """Reads segment ``seg`` once.  Returns (True, records) when none
+        of its untagged records is live, so the whole segment may drop;
+        otherwise retires its live records of an epoch below ``floor``
+        and returns (False, records)."""
+        size = self.wal.cfg.segment_size
+        untagged, tagged = [], []
+        for pos, rtype, payload in self.wal.iter_records(seg * size,
+                                                         (seg + 1) * size):
+            if (rtype not in (T_ENTRY, T_TOMBSTONE)
+                    or not entry_framed(rtype, payload)):
+                continue
+            # An entry starts as a tombstone does (keyspace, key, epoch);
+            # its value is not needed, and not copied.
+            ks_id, key, epoch = decode_tombstone(payload)
+            rec = (ks_id, key, None, epoch, pos, rtype == T_TOMBSTONE)
+            (tagged if epoch else untagged).append(rec)
+        read = len(untagged) + len(tagged)
+        if all(self._maybe_relocate(*rec, None) == Decision.REMOVE
+               for rec in untagged):
+            return True, read
+        filt = expiry_filter(floor)
+        for rec in tagged:
+            self._maybe_relocate(*rec, filt)
+        self._swept.add(seg)
+        return False, read
 
 
 class PruneController:
@@ -386,10 +483,11 @@ class PruneController:
       has aged out of the newest N epochs drop for free.
     - **Space amplification** (``space_amp_trigger``): a relocation pass
       runs when the physical WAL span exceeds the trigger × the estimated
-      live bytes.  The estimate self-corrects: each completed pass reports
-      its observed live fraction, which reprojects over the current span.
-      Until a first pass calibrates it, any span ≥ ``min_reclaim_bytes``
-      triggers.
+      live bytes.  Until a first pass runs, the estimate is counted
+      (``live_bytes_estimate``); each completed pass then reports its
+      observed live fraction, which reprojects over the current span.  So
+      a store whose only dead bytes are expired epochs, which expiry
+      drops, never relocates a record.
     """
 
     def __init__(self, relocator: Relocator, opts: Optional[PruneOptions] = None):
@@ -403,22 +501,45 @@ class PruneController:
         wal = self.relocator.wal
         return wal.tail - wal.first_live_pos
 
+    def live_bytes_estimate(self, opts: Optional[PruneOptions] = None
+                            ) -> Optional[int]:
+        """Estimated live bytes: the last completed pass's projection, or
+        before any pass the Large Table's live keys times the mean record
+        size the value WAL has appended since open.  That mean is taken
+        only once the appends hold ``min_reclaim_bytes`` (None before):
+        the few small rows a store writes of itself after a reopen, before
+        its first writes, would put the mean of a store of large records
+        far too low and start a pass that reclaims nothing.  Counting is
+        free, so no pass is forced to calibrate.  A cell's count is exact
+        at its flush, which leaves out entries into segments expiry
+        dropped; until its next flush it also counts its keys whose
+        segments dropped since."""
+        if self._live_bytes_est is not None:
+            return self._live_bytes_est
+        o = opts or self.opts
+        wal = self.relocator.wal
+        if (wal.records_appended == 0
+                or wal.bytes_appended < o.min_reclaim_bytes):
+            return None
+        entries = sum(c.approx_keys for _, c in self.relocator.table.all_cells())
+        return max(0, entries) * wal.bytes_appended // wal.records_appended
+
     def space_amp(self) -> float:
-        """Physical span / estimated live bytes (∞ until calibrated)."""
-        span = self._span()
-        est = self._live_bytes_est
+        """Physical span / estimated live bytes (∞ without an estimate)."""
+        span = self.relocator.wal.live_span_bytes()
+        est = self.live_bytes_estimate()
         if est is None or est <= 0:
             return float("inf") if span > 0 else 1.0
         return span / est
 
     def should_relocate(self, opts: Optional[PruneOptions] = None) -> bool:
         o = opts or self.opts
-        span = self._span()
+        span = self.relocator.wal.live_span_bytes()
         if span < o.min_reclaim_bytes:
             return False
-        est = self._live_bytes_est
+        est = self.live_bytes_estimate(o)
         if est is None:
-            return True                        # calibration pass
+            return False
         return span >= o.space_amp_trigger * max(est, 1)
 
     def epoch_floor(self, opts: Optional[PruneOptions] = None) -> Optional[int]:
@@ -437,12 +558,7 @@ class PruneController:
         relocated old-epoch record would both cost a pointless copy and
         poison its landing segment's epoch range, blocking that segment's
         own future expiry.  Untagged records (epoch 0) always survive."""
-        if floor is None:
-            return None
-
-        def filt(key: bytes, value: Optional[bytes], epoch: int) -> Decision:
-            return Decision.REMOVE if 0 < epoch < floor else Decision.KEEP
-        return filt
+        return None if floor is None else expiry_filter(floor)
 
     def _update_estimate(self) -> None:
         lp = self.relocator.last_pass
@@ -456,7 +572,7 @@ class PruneController:
         # over the REST of the span.  Projecting it over the whole span
         # would tag a freshly-compacted, all-live store with the pre-pass
         # dead fraction and re-trigger a pointless pass.
-        span = self._span()
+        span = self.relocator.wal.live_span_bytes()
         self._live_bytes_est = max(1, live + int(frac * max(0, span - live)))
 
     # ------------------------------------------------------------ grains
@@ -495,11 +611,13 @@ class PruneController:
         return self.prune_once(opts, force=False)
 
     def step(self, opts: Optional[PruneOptions] = None) -> int:
-        """One bounded relocation slice — the serving loop's unit of
+        """One bounded reclamation slice — the serving loop's unit of
         reclamation work.  Never blocks on another pruner (a busy lock
-        means reclamation is already being paid for elsewhere); starts a
-        pass only when the trigger policy says so, then keeps draining it
-        one ``batch_records`` slice at a time.  Returns records scanned."""
+        means reclamation is already being paid for elsewhere).  With no
+        pass in flight it expires first (``Relocator.expire_step``: a slice
+        that sweeps a mixed segment does nothing else); starts a pass only
+        when the trigger policy says so, then keeps draining it one
+        ``batch_records`` slice at a time.  Returns records scanned."""
         o = opts or self.opts
         if not self._lock.acquire(blocking=False):
             return 0
@@ -507,18 +625,24 @@ class PruneController:
             rel = self.relocator
             floor = self.epoch_floor(o)
             filt = self._expiry_filter(floor)
-            if not rel.scanning:
+            if floor is not None:
+                rel.note_expire_floor(floor)   # swept once the pass ends
+            if not rel.in_pass:
                 if floor is not None:
-                    rel.prune_epochs_below(floor)
+                    swept = rel.expire_step(floor)
+                    if swept:
+                        return swept           # one mixed segment a slice
                 if not self.should_relocate(o):
                     return 0
                 wal = rel.wal
                 cutoff = wal.first_live_pos + int(self._span()
                                                   * o.reclaim_fraction)
-                scanned = rel.relocate_step(o.batch_records, cutoff, filt)
+                with span("prune.relocate"):
+                    scanned = rel.relocate_step(o.batch_records, cutoff, filt)
             else:
-                scanned = rel.relocate_step(o.batch_records, filt=filt)
-            if not rel.scanning:               # pass just completed
+                with span("prune.relocate"):
+                    scanned = rel.relocate_step(o.batch_records, filt=filt)
+            if not rel.in_pass:                # pass just completed
                 self._update_estimate()
             return scanned
         finally:
@@ -543,7 +667,8 @@ class PruneThread:
     def _loop(self) -> None:
         while not self._stop.wait(self.interval):
             try:
-                self.controller.maybe_prune()
+                with span("bg.prune"):
+                    self.controller.maybe_prune()
             except Exception:  # pragma: no cover
                 import traceback
                 traceback.print_exc()

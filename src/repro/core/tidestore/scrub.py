@@ -122,8 +122,11 @@ class Scrubber:
                 findings.append({"pos": pos, "segment": seg, "kind": "io",
                                  "detail": str(e)})
                 break
+            bad = len(payload) < length or crc32(payload) != crc
+            if bad and wal.segment_missing(seg):
+                break                      # segment reclaimed mid-read
             checked += 1
-            if len(payload) < length or crc32(payload) != crc:
+            if bad:
                 if pos not in repaired:
                     # Repaired carcasses stay corrupt on disk until segment
                     # GC reclaims them; re-reporting (or re-quarantining)

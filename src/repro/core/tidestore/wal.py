@@ -355,6 +355,11 @@ class Wal:
         existing = self._scan_segments()
         self.first_live_pos = (min(existing) * self.cfg.segment_size) if existing else 0
         self._tail = (max(existing) * self.cfg.segment_size) if existing else 0
+        # Records and framed bytes appended since open (under _alloc_lock):
+        # their ratio is the mean record size the prune trigger projects
+        # over the Large Table's entries.
+        self.records_appended = 0
+        self.bytes_appended = 0
         if existing:
             self._tail = self._recover_tail(max(existing))
             self._dropped_segments = \
@@ -558,6 +563,8 @@ class Wal:
             fd = self._fd(seg, create=True)
             if epoch or rtype in (T_ENTRY, T_TOMBSTONE, T_BATCH):
                 self._note_epoch(seg, epoch)
+            self.records_appended += 1
+            self.bytes_appended += rec_len
             with self._dirty_lock:
                 self._dirty_segments.add(seg)
             token, ev = self._latch_open()
@@ -691,6 +698,8 @@ class Wal:
                 if m.any():
                     e = eps[m]
                     self._note_epoch_range(int(s), int(e.min()), int(e.max()))
+            self.records_appended += len(records)
+            self.bytes_appended += total
             with self._dirty_lock:
                 self._dirty_segments.update(int(s) for s in segs)
             token, ev = self._latch_open()
@@ -1017,35 +1026,48 @@ class Wal:
                 out[p] = (rtype, payload)
         return out
 
+    READ_AHEAD = 256 * 1024    # bytes iter_records reads at a time
+
     def iter_records(self, from_pos: int = 0,
                      stop_pos: Optional[int] = None) -> Iterator[tuple[int, int, bytes]]:
         """Replay iterator: yields (pos, type, payload); expands batches into
-        their sub-records (skipping torn batches wholesale)."""
+        their sub-records (skipping torn batches wholesale).  Reads up to
+        ``READ_AHEAD`` bytes of a segment at a time, not each record alone;
+        its callers scan records that are already complete (replay at
+        open, relocation below the processed watermark, sealed segments)."""
         seg_size = self.cfg.segment_size
         pos = max(from_pos, self.first_live_pos)
         tail = stop_pos if stop_pos is not None else self.tail
+        buf, base = b"", pos               # bytes read ahead from ``base``
         while pos < tail:
             if seg_size - pos % seg_size < HEADER_SIZE:
                 pos = (pos // seg_size + 1) * seg_size   # tiny tail padding
                 continue
-            hdr = self._pread_raw(pos, HEADER_SIZE)
-            if len(hdr) < HEADER_SIZE:
+            seg_end = (pos // seg_size + 1) * seg_size
+            if not base <= pos <= base + len(buf) - HEADER_SIZE:
+                buf, base = self._pread_raw(
+                    pos, min(self.READ_AHEAD, seg_end - pos)), pos
+            if len(buf) < HEADER_SIZE:
                 # Short read mid-log: the segment file was dropped by epoch
                 # pruning (possibly between the snapshot this replay started
                 # from and now).  Skip the hole, not the whole suffix.
                 seg = pos // seg_size
-                if self.segment_missing(seg) and (seg + 1) * seg_size < tail:
-                    pos = (seg + 1) * seg_size
+                if self.segment_missing(seg) and seg_end < tail:
+                    pos = seg_end
                     continue
                 break
-            rtype, length, crc = _HDR.unpack(hdr)
+            rtype, length, crc = _HDR.unpack_from(buf, pos - base)
             if rtype == T_PAD:
-                pos = (pos // seg_size + 1) * seg_size       # segment jump
+                pos = seg_end                                # segment jump
                 continue
             nxt = pos + HEADER_SIZE + length
-            if nxt > (pos // seg_size + 1) * seg_size or nxt > tail:
+            if nxt > seg_end or nxt > tail:
                 break                                        # torn tail
-            payload = self._pread_raw(pos + HEADER_SIZE, length)
+            if nxt > base + len(buf):
+                buf, base = self._pread_raw(
+                    pos, min(max(self.READ_AHEAD, nxt - pos),
+                             seg_end - pos)), pos
+            payload = buf[pos + HEADER_SIZE - base:nxt - base]
             if crc32(payload) != crc:
                 # Torn payload (poisoned header from a failed copy, or
                 # latent corruption): skipped, never yielded.
@@ -1208,15 +1230,29 @@ class Wal:
             return dict(self._segment_epochs)
 
     def segments_expired_below_epoch(self, epoch: int) -> list[int]:
-        """Whole segments whose max epoch < ``epoch`` — droppable without
-        relocating a single byte (the paper's epoch-based pruning).
+        """Whole segments of tagged records whose max epoch < ``epoch`` —
+        droppable without relocating a single byte (the paper's epoch-based
+        pruning).
 
         Expired segments anywhere in the live span qualify, not just a
         prefix: ``drop_segments`` supports mid-log holes, so an old-epoch
         segment sandwiched between newer ones is reclaimed immediately
         instead of waiting for relocation to clear everything below it.
-        Segments with no recorded epoch range (e.g. ranges lost to a crash
-        before the next control-region snapshot) are never dropped."""
+        Untagged records (epoch 0) never expire, so a segment whose range
+        starts at 0 holds one and never drops here, whether its range was
+        noted at append, restored from the control region or rebuilt by
+        replay; ``segments_mixed_below_epoch`` lists those whose tagged
+        records all expired.  Segments with no recorded epoch range (e.g.
+        ranges lost to a crash before the next control-region snapshot)
+        are never dropped."""
+        return self._segments_below_epoch(epoch, mixed=False)
+
+    def segments_mixed_below_epoch(self, epoch: int) -> list[int]:
+        """Segments holding untagged records beside tagged ones that all
+        lie below ``epoch``: range (0, hi) with 0 < hi < ``epoch``."""
+        return self._segments_below_epoch(epoch, mixed=True)
+
+    def _segments_below_epoch(self, epoch: int, mixed: bool) -> list[int]:
         first_seg = self.first_live_pos // self.cfg.segment_size
         tail_seg = self.tail // self.cfg.segment_size
         out = []
@@ -1225,9 +1261,21 @@ class Wal:
                 if seg in self._dropped_segments:
                     continue
                 rng = self._segment_epochs.get(seg)
-                if rng is not None and rng[1] < epoch:
+                if (rng is not None and 0 < rng[1] < epoch
+                        and (rng[0] == 0) == mixed):
                     out.append(seg)
         return out
+
+    def live_span_bytes(self) -> int:
+        """Bytes the live span holds on disk: from the GC watermark to the
+        tail, less the segments epoch pruning dropped out of its middle."""
+        seg_size = self.cfg.segment_size
+        tail = self.tail
+        first = self.first_live_pos
+        with self._epoch_lock:
+            holes = sum(first // seg_size <= s < tail // seg_size
+                        for s in self._dropped_segments)
+        return tail - first - holes * seg_size
 
     def pos_live(self, pos: int) -> bool:
         """False for positions reclaimed by GC or epoch pruning: below the
@@ -1255,7 +1303,8 @@ class Wal:
         for s in sorted(segs):
             if s >= tail_seg:
                 continue                   # never the open tail segment
-            self._dropped_segments.add(s)
+            with self._epoch_lock:
+                self._dropped_segments.add(s)
             try:
                 os.unlink(self._segment_path(s))
                 self.metrics.add(segments_deleted=1)

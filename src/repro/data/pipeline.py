@@ -47,11 +47,13 @@ class ContentAddressedStore:
         return hashlib.blake2b(sample, digest_size=32).digest()
 
     def put(self, sample: bytes, epoch: int = 0) -> bytes:
+        """Put-if-absent; data epoch ``epoch`` is written as store epoch
+        ``epoch + 1``, since the store never expires epoch 0."""
         key = self.key_of(sample)
         if self.db.exists(key, keyspace="samples"):
             self.dedup_hits += 1          # bloom+index, no value fetched
             return key
-        self.db.put(key, sample, keyspace="samples", epoch=epoch)
+        self.db.put(key, sample, keyspace="samples", epoch=epoch + 1)
         self.inserted += 1
         return key
 
@@ -63,7 +65,9 @@ class ContentAddressedStore:
                 for row in tokens]
 
     def expire_epochs_below(self, epoch: int) -> int:
-        return self.db.prune_epochs_below(epoch)
+        """Drops the segments whose samples all have a data epoch below
+        ``epoch``."""
+        return self.db.prune_epochs_below(epoch + 1)
 
     def close(self) -> None:
         self.db.close()

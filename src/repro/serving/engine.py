@@ -148,6 +148,7 @@ class KvBatchServer:
                             if prune_opts is not None else None)
         self.prune_steps = 0
         self.prune_scanned = 0
+        self.prune_step_s = 0.0          # wall seconds inside the slices
         # Scrubbing rides idle steps the same way pruning does: when
         # scrub=True (and the engine exposes scrub_step), an idle step()
         # CRC-verifies one sealed WAL segment — a busy server defers
@@ -351,7 +352,10 @@ class KvBatchServer:
     def _maybe_prune(self) -> None:
         if self._prune_step is None:
             return
-        scanned = self._prune_step(self.prune_opts)
+        t = time.perf_counter()
+        with span("prune.step"):
+            scanned = self._prune_step(self.prune_opts)
+        self.prune_step_s += time.perf_counter() - t
         if scanned:
             self.prune_steps += 1
             self.prune_scanned += scanned
@@ -522,6 +526,9 @@ class KvBatchServer:
                                if self.batches_served else 0.0),
                 "prune_steps": self.prune_steps,
                 "prune_scanned": self.prune_scanned,
+                "prune_step_s": self.prune_step_s,
+                "write_epoch": (self.write_opts.epoch
+                                if self.write_opts is not None else 0),
                 "scrub_steps": self.scrub_steps,
                 "scrub_checked": self.scrub_checked,
                 "serve_errors": self.serve_errors,

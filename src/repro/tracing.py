@@ -30,8 +30,11 @@ SPANS = (
     "wal.value_read", "wal.index_pread", "wal.append_many",
     # device forms (kernels/*/ops.py)
     "lookup.device", "lookup.host_search", "bloom.device",
-    # background threads (snapshot tick, flusher pool)
-    "bg.snapshot", "bg.flush_cell",
+    # reclamation (core/tidestore/relocate.py; the served slice in
+    # serving/engine.py)
+    "prune.step", "prune.expire", "prune.relocate",
+    # background threads (snapshot tick, flusher pool, prune thread)
+    "bg.snapshot", "bg.flush_cell", "bg.prune",
 )
 
 

@@ -17,7 +17,8 @@ import pytest
 from hypothesis_compat import HealthCheck, given, settings, st
 
 from repro.core.tidestore import (DbConfig, KeyspaceConfig, PruneController,
-                                  PruneOptions, ShardedTideDB, TideDB)
+                                  PruneOptions, ShardedTideDB, TideDB,
+                                  WriteOptions)
 from repro.core.tidestore.db import clamp_copy_threads
 from repro.core.tidestore.snapshot import (CONTROL_FALLBACK, CONTROL_FILE,
                                            read_control_region)
@@ -115,13 +116,26 @@ class TestBatchedDispatch:
 # ------------------------------------------------------- trigger policy
 class TestPruneController:
     def test_uncalibrated_triggers_above_min_bytes(self, tmpdir):
-        opts = PruneOptions(min_reclaim_bytes=1024)
+        """Before any pass the live bytes are counted (the table's live
+        keys × the mean record appended), not calibrated by a forced pass:
+        an all-live store above min_reclaim_bytes stays put, churn past
+        the trigger starts a pass."""
+        opts = PruneOptions(min_reclaim_bytes=1024)      # trigger 2.0
         with TideDB(tmpdir, small_cfg(prune=opts)) as db:
             pc = db.prune_controller
             assert not pc.should_relocate()          # empty store
-            for k in keys_n(50):
+            ks = keys_n(50)
+            for k in ks:
                 db.put(k, bytes(100))
-            assert pc.should_relocate()              # uncalibrated: span >= min
+            assert db.value_wal.live_span_bytes() >= opts.min_reclaim_bytes
+            assert pc.space_amp() == pytest.approx(1.0, abs=0.05)
+            assert not pc.should_relocate()          # all live: no pass
+            assert db.prune_step() == 0
+            for _ in range(2):
+                for k in ks:
+                    db.put(k, bytes(100))
+            assert pc.space_amp() > 2.0
+            assert pc.should_relocate()              # uncalibrated, amp 3
             out = db.prune()
             assert out["triggered"] and out["space_amp"] < float("inf")
 
@@ -588,6 +602,25 @@ class TestServerPruning:
             for i, k in enumerate(ks):
                 assert db.get(k) == b"new-%06d" % i
 
+    def test_server_times_its_prune_slices(self, tmpdir):
+        """prune_step_s sums the wall time of the served slices, which run
+        inside the served steps; without prune_opts it stays 0."""
+        from repro.serving.engine import KvBatchServer
+        opts = PruneOptions(retain_epochs=1, min_reclaim_bytes=1024)
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            timed = KvBatchServer(db, max_batch=64, prune_opts=opts)
+            bare = KvBatchServer(db, max_batch=64)
+            for srv in (timed, bare):
+                for ep in (1, 2, 3):
+                    srv.write_opts = WriteOptions(epoch=ep)
+                    for k in keys_n(64, tag=f"{id(srv)}-{ep}"):
+                        srv.submit_put(k, bytes(200))
+                    srv.step()
+            s = timed.stats()
+            assert 0 < s["prune_step_s"] < s["step_wall_s"]
+            assert db.metrics.segments_pruned > 0
+            assert bare.stats()["prune_step_s"] == 0.0
+
     def test_server_prune_disabled_by_default(self, tmpdir):
         from repro.serving.engine import KvBatchServer
         with TideDB(tmpdir, small_cfg()) as db:
@@ -690,3 +723,296 @@ class TestCopyThreadClamp:
         with ShardedTideDB(tmpdir, cfg, n_shards=2) as sdb:
             assert sdb._copy_pool.threads == cores
             assert sdb.stats()["copy_threads_clamped"] >= 3
+
+
+# ------------------------------------------ untagged records never expire
+def _untagged_then_epochs(db, n_untagged=120, per_epoch=60, epochs=4):
+    """Untagged records first, then ``epochs`` epochs of tagged ones: the
+    segment where the untagged records end holds epoch 1 too."""
+    untagged = keys_n(n_untagged, tag="live-")
+    for k in untagged:
+        db.put(k, bytes(200))
+    tagged = {ep: keys_n(per_epoch, tag=f"ep{ep}-")
+              for ep in range(1, epochs + 1)}
+    for ep, ks in tagged.items():
+        for k in ks:
+            db.put(k, bytes(200), epoch=ep)
+    return untagged, tagged
+
+
+def _sealed_ranges(db):
+    """Epoch ranges of the segments below the open tail segment (which
+    takes the store's own untagged rows after a reopen)."""
+    wal = db.value_wal
+    tail = wal.tail // wal.cfg.segment_size
+    return {s: tuple(r) for s, r in wal.segment_epochs().items() if s < tail}
+
+
+class TestUntaggedNeverExpire:
+    @pytest.mark.parametrize("reopen", [None, "snapshot", "replay"])
+    def test_untagged_and_mixed_segments_never_drop_whole(self, tmpdir,
+                                                          reopen):
+        cfg = small_cfg()
+        db = TideDB(tmpdir, cfg)
+        untagged, tagged = _untagged_then_epochs(db)
+        if reopen == "snapshot":
+            db.snapshot_now()
+        ranges = _sealed_ranges(db)
+        mixed = [s for s, (lo, hi) in ranges.items() if lo == 0 < hi]
+        assert mixed and any(r == (0, 0) for r in ranges.values())
+        if reopen is not None:
+            db.close(flush=False)            # replay: no control region
+            assert (reopen == "snapshot") == os.path.exists(
+                os.path.join(tmpdir, CONTROL_FILE))
+            db = TideDB(tmpdir, cfg)
+            after = _sealed_ranges(db)       # restored or rebuilt alike
+            assert {s: after.get(s) for s in ranges} == ranges
+        wal = db.value_wal
+        try:
+            expired = wal.segments_expired_below_epoch(1 << 40)
+            assert expired and all(ranges[s][0] > 0 for s in expired)
+            assert set(mixed) <= set(wal.segments_mixed_below_epoch(1 << 40))
+            assert db.prune_epochs_below(4) > 0
+            for s in mixed:                  # live untagged records in it
+                assert not wal.segment_missing(s)
+            for k in untagged:
+                assert db.get(k) == bytes(200)
+            for ep in (1, 2):                # retired in place or dropped
+                assert all(db.get(k) is None for k in tagged[ep])
+            assert all(db.get(k) == bytes(200) for k in tagged[4])
+            assert db.metrics.relocated_entries == 0
+        finally:
+            db.close()
+
+    def test_mixed_segment_of_dead_untagged_records_drops(self, tmpdir):
+        """Untagged records overwritten later (as the __system rows each
+        fold rewrites) keep no segment alive."""
+        with TideDB(tmpdir, small_cfg()) as db:
+            untagged, tagged = _untagged_then_epochs(db)
+            mixed = db.value_wal.segments_mixed_below_epoch(1 << 40)
+            for k in untagged:
+                db.put(k, b"moved")
+            for k in tagged[4]:
+                db.put(k, bytes(200), epoch=5)
+            db.prune_epochs_below(5)
+            assert all(db.value_wal.segment_missing(s) for s in mixed)
+            assert all(db.get(k) == b"moved" for k in untagged)
+
+    def test_expired_epochs_drop_without_relocating(self, tmpdir):
+        """A store of untagged records plus epochs that expire, reclaimed
+        by the served slice: segments drop, no record is relocated (no
+        pass is forced to calibrate the live bytes)."""
+        opts = PruneOptions(retain_epochs=2, space_amp_trigger=3.0,
+                            reclaim_fraction=0.25, batch_records=64,
+                            min_reclaim_bytes=1024)
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            untagged = keys_n(150, tag="live-")
+            db.put_many([(k, bytes(200)) for k in untagged])
+            puts = {}
+            for ep in range(1, 13):
+                puts[ep] = keys_n(100, tag=f"ep{ep}-")
+                for i in range(0, 100, 25):
+                    db.put_many([(k, bytes(200)) for k in puts[ep][i:i + 25]],
+                                epoch=ep)
+                    db.prune_step()
+            assert db.metrics.segments_pruned > 0
+            assert db.metrics.relocated_entries == 0
+            assert db.metrics.relocated_bytes == 0
+            assert all(db.get(k) == bytes(200) for k in untagged)
+            assert all(db.get(k) == bytes(200) for k in puts[12] + puts[11])
+            assert all(db.get(k) is None for ep in range(1, 9)
+                       for k in puts[ep])
+
+    def test_overwrites_still_trigger_relocation(self, tmpdir):
+        opts = PruneOptions(space_amp_trigger=3.0, reclaim_fraction=1.0,
+                            batch_records=64, min_reclaim_bytes=1024)
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            ks = keys_n(100)
+            db.put_many([(k, bytes(200)) for k in ks])
+            assert db.prune_step() == 0      # all live
+            for r in range(3):
+                db.put_many([(k, b"%d" % r * 200) for k in ks])
+            assert db.prune_controller.space_amp() > 3.0
+            while db.prune_step() or db.relocator.scanning:
+                pass
+            assert db.metrics.relocated_entries > 0
+            assert all(db.get(k) == b"2" * 200 for k in ks)
+
+    def test_overwrites_after_expired_epochs_still_trigger(self, tmpdir):
+        """Keys of the epochs expiry dropped leave the live count when
+        their cells flush (the flush drops entries into reclaimed log),
+        so overwrites after many dropped epochs still start a pass at the
+        trigger."""
+        opts = PruneOptions(retain_epochs=2, space_amp_trigger=3.0,
+                            reclaim_fraction=1.0, batch_records=64,
+                            min_reclaim_bytes=1024)
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            live = keys_n(100, tag="live-")
+            db.put_many([(k, bytes(200)) for k in live])
+            for ep in range(1, 21):
+                db.put_many([(k, bytes(200))
+                             for k in keys_n(100, tag=f"ep{ep}-")], epoch=ep)
+                while db.prune_step():
+                    pass
+            assert db.metrics.segments_pruned > 0
+            assert db.metrics.relocated_entries == 0
+            db.snapshot_now()                # flushes every dirty cell
+            est = db.prune_controller.live_bytes_estimate()
+            assert est < 2 * 300 * 250       # 300 live records, not 2,100
+            for r in range(14):
+                db.put_many([(k, b"%d" % r * 100) for k in live])
+            assert db.prune_controller.space_amp() > 3.0
+            while db.prune_step() or db.relocator.scanning:
+                pass
+            assert db.metrics.relocated_entries > 0
+            assert all(db.get(k) == b"13" * 100 for k in live)
+            assert all(db.get(k) == bytes(200)
+                       for ep in (19, 20) for k in keys_n(100, tag=f"ep{ep}-"))
+            assert all(db.get(k) is None for k in keys_n(100, tag="ep5-"))
+
+    def test_served_slices_sweep_one_mixed_segment_each(self, tmpdir):
+        """The served slice sweeps at most one mixed segment, leaving the
+        rest to later slices; ``scanning`` holds until none is left."""
+        opts = PruneOptions(retain_epochs=1, space_amp_trigger=1e9,
+                            min_reclaim_bytes=1 << 40)
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            for ep in range(1, 9):           # an untagged row per segment
+                for i in range(0, 120, 10):
+                    db.put_many([(k, bytes(200)) for k in
+                                 keys_n(120, tag=f"e{ep}-")[i:i + 10]],
+                                epoch=ep)
+                    db.put(keys_n(1, tag=f"u{ep}.{i}-")[0], b"u")
+            wal = db.value_wal
+            mixed = wal.segments_mixed_below_epoch(8)
+            assert len(mixed) >= 3
+            slices = 0
+            while db.prune_step() or db.relocator.scanning:
+                slices += 1
+                assert not db.relocator.in_pass
+            assert slices >= len(mixed)
+            assert not [s for s in wal.segments_mixed_below_epoch(8)
+                        if s not in db.relocator._swept]
+            # epoch 7 may share a segment with epoch 8, which stays
+            assert all(db.get(k) is None for ep in range(1, 7)
+                       for k in keys_n(120, tag=f"e{ep}-"))
+            assert all(db.get(k) == bytes(200)
+                       for k in keys_n(120, tag="e8-"))
+            assert all(db.get(keys_n(1, tag=f"u{ep}.{i}-")[0]) == b"u"
+                       for ep in range(1, 9) for i in range(0, 120, 10))
+            assert db.metrics.relocated_entries == 0
+
+    def test_small_rows_after_reopen_start_no_pass(self, tmpdir):
+        """After a reopen, the few small rows a store writes before its
+        first writes give no mean record: the live bytes stay unestimated
+        (no pass) until the appends hold min_reclaim_bytes."""
+        opts = PruneOptions(space_amp_trigger=3.0, reclaim_fraction=0.25,
+                            min_reclaim_bytes=64 * 1024)
+        cfg = small_cfg(prune=opts)
+        with TideDB(tmpdir, cfg) as db:
+            db.put_many([(k, bytes(1024)) for k in keys_n(200, tag="live-")])
+        with TideDB(tmpdir, cfg) as db:
+            pc = db.prune_controller
+            for k in keys_n(20, tag="row-"):
+                db.put(k, bytes(80))
+            assert db.value_wal.live_span_bytes() > 3 * opts.min_reclaim_bytes
+            assert pc.live_bytes_estimate() is None
+            assert not pc.should_relocate()
+            assert db.prune_step() == 0
+            db.put_many([(k, bytes(1024)) for k in keys_n(100, tag="new-")])
+            assert db.value_wal.bytes_appended >= opts.min_reclaim_bytes
+            assert pc.space_amp() == pytest.approx(1.0, abs=0.2)
+            assert db.prune_step() == 0
+            assert db.metrics.relocated_entries == 0
+
+    def test_sweeps_a_pass_deferred_are_in_flight_after_it(self, tmpdir):
+        """Expiry waits while a relocation pass is in flight; once the pass
+        ends, the mixed segments below the floor that rose meanwhile count
+        as in flight (``scanning``) until swept, so a slice that starts
+        with ``scanning`` False leaves no expired record behind."""
+        opts = PruneOptions(retain_epochs=1, space_amp_trigger=1e9,
+                            batch_records=4, min_reclaim_bytes=1 << 40)
+
+        def put_epoch(db, ep):               # an untagged row per segment
+            for i in range(0, 120, 10):
+                db.put_many([(k, bytes(200)) for k in
+                             keys_n(120, tag=f"e{ep}-")[i:i + 10]], epoch=ep)
+                db.put(keys_n(1, tag=f"u{ep}.{i}-")[0], b"u")
+
+        with TideDB(tmpdir, small_cfg(prune=opts)) as db:
+            rel = db.relocator
+            for ep in range(1, 3):
+                put_epoch(db, ep)
+            rel.relocate_step(4, db.value_wal.tail)   # a pass starts
+            assert rel.in_pass
+            for ep in range(3, 9):
+                put_epoch(db, ep)
+            assert db.prune_step() and rel.in_pass    # expiry deferred
+            while rel.in_pass:
+                db.prune_step()
+            assert rel.scanning                       # sweeps still due
+            for _ in range(1000):
+                scanning = rel.scanning
+                db.prune_step()
+                if not scanning:
+                    break
+            assert not rel.scanning
+            # epoch 7 may share a segment with epoch 8, which stays
+            assert all(db.get(k) is None for ep in range(1, 7)
+                       for k in keys_n(120, tag=f"e{ep}-"))
+            assert all(db.get(k) == bytes(200)
+                       for k in keys_n(120, tag="e8-"))
+            assert all(db.get(keys_n(1, tag=f"u{ep}.{i}-")[0]) == b"u"
+                       for ep in range(1, 9) for i in range(0, 120, 10))
+
+    def test_sweeps_race_foreground_writes(self, tmpdir):
+        """Expiry sweeps mixed segments while another thread overwrites
+        untagged keys and writes new epochs: no acknowledged untagged
+        write or retained epoch is lost."""
+        import sys
+        untagged = keys_n(3, tag="u-")
+        latest = {}
+        stop = threading.Event()
+        errors = []
+        with TideDB(tmpdir, small_cfg()) as db:
+            for k in untagged:
+                db.put(k, b"0" * 100)
+                latest[k] = b"0" * 100
+
+            def writer():
+                try:
+                    for ep in range(1, 40):
+                        db.put_many([(k, bytes(150)) for k in
+                                     keys_n(30, tag=f"w{ep}-")], epoch=ep)
+                        k = untagged[ep % len(untagged)]
+                        v = b"%d" % ep * 50
+                        db.put(k, v)
+                        latest[k] = v
+                except Exception as e:          # pragma: no cover
+                    errors.append(e)
+                finally:
+                    stop.set()
+
+            old = sys.getswitchinterval()
+            sys.setswitchinterval(1e-5)
+            t = threading.Thread(target=writer)
+            try:
+                t.start()
+                while not stop.is_set():
+                    floor = db.prune_controller.epoch_floor(
+                        PruneOptions(retain_epochs=2))
+                    if floor is not None:
+                        db.prune_epochs_below(floor)
+                t.join(timeout=30)
+            finally:
+                sys.setswitchinterval(old)
+            assert not t.is_alive() and not errors
+            db.prune_epochs_below(38)
+            assert db.metrics.segments_pruned > 0
+            for k in untagged:
+                assert db.get(k) == latest[k]
+            for ep in range(1, 37):              # dropped or retired
+                assert all(db.get(k) is None
+                           for k in keys_n(30, tag=f"w{ep}-"))
+            for ep in (38, 39):
+                assert all(db.get(k) == bytes(150)
+                           for k in keys_n(30, tag=f"w{ep}-"))

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config
-from repro.core.checkpoint import CheckpointManager
+from repro.core.checkpoint import CheckpointManager, _key
 from repro.data.pipeline import ContentAddressedStore, synthetic_batch
 from repro.distributed.compression import (compressed_psum,
                                            make_error_feedback_compressor,
@@ -96,6 +96,33 @@ class TestCheckpointRestart:
             ckpt.save(s, params)
         steps = ckpt.list_steps()
         assert 5 in steps and 4 in steps
+        ckpt.close()
+
+    def test_step_zero_is_retired_like_any_other(self, tmpdir, monkeypatch):
+        """Step 0 is written as epoch 1, not as the store's never-expiring
+        epoch 0: once keep_last newer steps exist, its segments drop.  The
+        logs' segments are cut to 64 KiB so that a step fills several."""
+        import repro.core.checkpoint as checkpoint
+        real = checkpoint.WalConfig
+        monkeypatch.setattr(checkpoint, "WalConfig", lambda segment_size, **kw:
+                            real(segment_size=65536, **kw))
+        params = {"w": jnp.arange(65536, dtype=jnp.float32)}   # 256 KiB
+        ckpt = CheckpointManager(tmpdir, keep_last=2, chunk_bytes=16384,
+                                 background=False)
+        ckpt.save(0, params)
+        ckpt.save(1, params)
+        assert 0 in ckpt.list_steps()
+        ckpt.save(2, params)
+        ckpt.save(3, params)
+        steps = ckpt.list_steps()
+        assert 0 not in steps and {2, 3} <= set(steps)
+        assert ckpt.db.get(_key("ckpt", 0, "w", 0), keyspace="ckpt") is None
+        assert ckpt.db.metrics.segments_pruned > 0
+        like = {"w": jax.ShapeDtypeStruct((65536,), jnp.float32)}
+        restored, step = ckpt.restore(like)
+        assert step == 3
+        np.testing.assert_array_equal(np.asarray(restored["w"]),
+                                      np.asarray(params["w"]))
         ckpt.close()
 
     def test_elastic_restore_with_shardings(self, tmpdir):
@@ -221,4 +248,21 @@ class TestDataPipeline:
         sample = store.get(keys1[0])
         np.testing.assert_array_equal(
             np.frombuffer(sample, np.int32), toks[0])
+        store.close()
+
+    def test_data_epoch_zero_expires(self, tmpdir, monkeypatch):
+        """Data epoch 0 is written as store epoch 1, so it expires like the
+        others (the store never expires epoch 0).  Segments are cut to
+        64 KiB so that an epoch's samples fill several."""
+        import repro.data.pipeline as pipeline
+        real = pipeline.WalConfig
+        monkeypatch.setattr(pipeline, "WalConfig", lambda segment_size, **kw:
+                            real(segment_size=65536, **kw))
+        store = ContentAddressedStore(tmpdir, background=False)
+        keys = {ep: store.ingest_tokens(
+                    synthetic_batch(ep, 1024, 32, 1000, seed=7)["tokens"],
+                    epoch=ep) for ep in range(3)}
+        assert store.expire_epochs_below(2) > 0
+        assert all(store.get(k) is None for k in keys[0])
+        assert all(store.get(k) is not None for k in keys[2])
         store.close()
