@@ -23,6 +23,11 @@ Returns (index, found, windows-used) per query.  ``index`` is -1 where the
 budget ran out (rare, non-uniform adversarial input); the host resolves
 only those queries, mirroring the engine's linear-probe → bisection
 fallback.
+
+One jitted program takes any number of queries: a loop inside it runs the
+kernel on ``_Q_CHUNK`` queries at a time, as many chunks as the real query
+count needs, so a batch costs one dispatch while each Pallas invocation
+keeps its per-query columns in SMEM at the chunk's size.
 """
 from __future__ import annotations
 
@@ -36,6 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import platform
 
 TILE = 1024                       # keys per (8, 128) i32 tile
+_Q_CHUNK = 256                    # queries per Pallas invocation
 _SIGN = -2**31                    # u32 order == i32 order after x ^ _SIGN
 
 
@@ -105,15 +111,38 @@ def _as_i32(x):
     return x.astype(jnp.int32)
 
 
+def _lookup_chunk(n, queries, base, count, frac, keys, *, tiles: int,
+                  max_iters: int):
+    """The kernel over one chunk of ``_Q_CHUNK`` queries: its five
+    scalar-prefetch operands and three outputs live in SMEM."""
+    kernel = functools.partial(_kernel, tiles=tiles, max_iters=max_iters)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,        # n, queries, base, count, frac
+            grid=(_Q_CHUNK,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # column in HBM
+            out_specs=[smem, smem, smem],
+            scratch_shapes=[pltpu.VMEM((tiles, 8, 128), jnp.int32),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.SMEM((2,), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((_Q_CHUNK,), jnp.int32)] * 3,
+        interpret=platform.interpret(),
+    )(n, queries, base, count, frac, keys)
+
+
 def optimistic_lookup(queries: jax.Array, keys: jax.Array, n_keys=None,
-                      segments=None, *, window: int = 2048,
+                      segments=None, n_queries=None, *, window: int = 2048,
                       max_iters: int = 4):
     """queries (Q,) u32; keys (N,) u32 sorted ascending, of which the first
     ``n_keys`` (an i32 scalar; default N) are real and the rest padding
     that sorts last.  ``segments`` is ``(base, count, frac)``, three (Q,)
     arrays: query q's key lies in ``keys[base:base+count]`` at fractional
-    position ``frac/2³²`` of that slice's key range.  ``window`` rounds up
-    to whole tiles of 1024 keys.
+    position ``frac/2³²`` of that slice's key range.  Of the queries the
+    first ``n_queries`` (an i32 scalar; default Q) are real: the chunks of
+    ``_Q_CHUNK`` that hold none of them are not searched and come back
+    unresolved.  ``window`` rounds up to whole tiles of 1024 keys.
 
     → (idx (Q,) i32 [-1 if unresolved], found (Q,) bool, iters (Q,) i32).
     """
@@ -123,25 +152,26 @@ def optimistic_lookup(queries: jax.Array, keys: jax.Array, n_keys=None,
     if segments is None:
         segments = (jnp.zeros(Q, jnp.int32), jnp.broadcast_to(n, (Q,)),
                     queries)
-    base, count, frac = (_as_i32(a) for a in segments)
+    q_pad = -(-Q // _Q_CHUNK) * _Q_CHUNK
+    cols = [jnp.pad(_as_i32(a), (0, q_pad - Q))
+            for a in (queries, *segments)]
+    n_q = Q if n_queries is None else n_queries
+    chunks = (jnp.asarray(n_q, jnp.int32) + _Q_CHUNK - 1) // _Q_CHUNK
     n_pad = -(-N // TILE) * TILE
     keys = jnp.pad(_as_i32(keys) ^ _SIGN, (0, n_pad - N),
-                   constant_values=2**31 - 1)
+                   constant_values=2**31 - 1).reshape(n_pad // TILE, 8, 128)
     tiles = min(max(1, -(-window // TILE)), n_pad // TILE)
-    kernel = functools.partial(_kernel, tiles=tiles, max_iters=max_iters)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    idx, found, iters = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,        # n, queries, base, count, frac
-            grid=(Q,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # column in HBM
-            out_specs=[smem, smem, smem],
-            scratch_shapes=[pltpu.VMEM((tiles, 8, 128), jnp.int32),
-                            pltpu.SemaphoreType.DMA(()),
-                            pltpu.SMEM((2,), jnp.int32)]),
-        out_shape=[jax.ShapeDtypeStruct((Q,), jnp.int32)] * 3,
-        interpret=platform.interpret(),
-    )(n, _as_i32(queries), base, count, frac,
-      keys.reshape(n_pad // TILE, 8, 128))
-    return idx, found.astype(jnp.bool_), iters
+
+    def chunk(c, outs):
+        off = c * _Q_CHUNK
+        got = _lookup_chunk(
+            n, *(jax.lax.dynamic_slice_in_dim(a, off, _Q_CHUNK)
+                 for a in cols), keys, tiles=tiles, max_iters=max_iters)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(o, g, off, 0)
+                     for o, g in zip(outs, got))
+
+    idx, found, iters = jax.lax.fori_loop(
+        0, chunks, chunk, (jnp.full(q_pad, -1, jnp.int32),
+                           jnp.zeros(q_pad, jnp.int32),
+                           jnp.zeros(q_pad, jnp.int32)))
+    return idx[:Q], found[:Q].astype(jnp.bool_), iters[:Q]

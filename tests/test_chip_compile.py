@@ -2,9 +2,9 @@
 
 The chip's compiler is installed even where no chip is attached, and
 compiles for a described topology: each test lowers a device form at the
-shapes ``chip_smoke.py`` dispatches.  The lookup is a Pallas kernel, so its
-test checks that Mosaic compiled it into the program (a
-``tpu_custom_call``); the Bloom probe is a plain gather that XLA compiles.
+shapes ``chip_smoke.py`` or a benchmark cell dispatches.  The lookup is a
+Pallas kernel, so its tests check that Mosaic compiled it into the program
+(a ``tpu_custom_call``); the Bloom probe is a plain gather that XLA compiles.
 Nothing runs, so these say nothing about results or times.
 
 The process's backend is still the CPU, where the lookup kernel would
@@ -57,8 +57,8 @@ def _compiled_text(fn, *specs) -> str:
 
 
 # chip_smoke.py: 1 M keys over 256 cells → the touched cells' key column
-# pads to 2^20; queries go out 256 at a time; window_entries=800 is one
-# tile.
+# pads to 2^20; a call of up to 256 queries runs one chunk;
+# window_entries=800 is one tile.
 @pytest.mark.parametrize("n_pad", [2**20, 2**22])
 def test_optimistic_lookup_compiles_for_v5e(one_chip, mosaic, n_pad):
     q = 256
@@ -71,6 +71,23 @@ def test_optimistic_lookup_compiles_for_v5e(one_chip, mosaic, n_pad):
         _spec((), jnp.int32, one_chip), _spec((q,), jnp.int32, one_chip),
         _spec((q,), jnp.int32, one_chip), _spec((q,), jnp.uint32, one_chip))
     assert "tpu_custom_call" in text
+
+
+# kv1k-uniform.exists: ~19.5 k Bloom positives a step pad to 128 chunks of
+# 256 queries, run by one program whose loop takes the real query count as
+# data; each chunk's kernel keeps its columns in SMEM at 256 queries.
+def test_one_program_lookup_compiles_for_v5e(one_chip, mosaic):
+    q, n_pad = 128 * 256, 2**20
+    fn = functools.partial(optimistic_lookup, window=800, max_iters=4)
+    text = _compiled_text(
+        lambda qs, keys, n, base, count, frac, n_q:
+            fn(qs, keys, n, (base, count, frac), n_q),
+        _spec((q,), jnp.uint32, one_chip), _spec((n_pad,), jnp.uint32,
+                                                 one_chip),
+        _spec((), jnp.int32, one_chip), _spec((q,), jnp.int32, one_chip),
+        _spec((q,), jnp.int32, one_chip), _spec((q,), jnp.uint32, one_chip),
+        _spec((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in text and "while" in text
 
 
 # chip_smoke.py: a 32768-key exists batch over 256 cells of 2048 words each

@@ -256,6 +256,63 @@ class TestOptimisticLookup:
         np.testing.assert_array_equal(idx, np.asarray(ridx))
         np.testing.assert_array_equal(found, np.asarray(rfound))
 
+    # Query counts on both sides of one chunk of 256 and of the chunk
+    # buckets (1, 2, 4, 128 chunks); key counts on both sides of the
+    # column's 4096-key floor.
+    @pytest.mark.parametrize("q", [1, 255, 256, 257, 3 * 256 + 17,
+                                   76 * 256 + 5])
+    @pytest.mark.parametrize("n", [4095, 4097])
+    @pytest.mark.parametrize("cells", [None, 4])
+    def test_one_dispatch_over_every_chunk(self, q, n, cells):
+        """However many queries, one call is one dispatch and answers
+        exactly the real queries as the host's ``searchsorted`` does;
+        with ``cells`` each query interpolates inside its own cell, as the
+        store's concatenations do."""
+        rng = np.random.default_rng(q * n)
+        keys = np.sort(rng.choice(np.unique(rng.integers(
+            0, 2**32, n + 64, dtype=np.uint32)), n, replace=False))
+        queries = np.concatenate([
+            rng.choice(keys, q // 2),
+            rng.integers(0, 2**32, q - q // 2, dtype=np.uint32)])
+        segments = None
+        if cells:
+            kcell = ((keys.astype(np.uint64) * cells) >> 32).astype(np.int64)
+            starts = np.searchsorted(kcell, np.arange(cells))
+            sizes = np.bincount(kcell, minlength=cells)
+            qcell = (queries.astype(np.uint64) * cells) >> 32
+            segments = (starts[qcell], sizes[qcell], (queries.astype(
+                np.uint64) * cells).astype(np.uint32))
+        idx, found, unresolved, copies = lookup_indices_batch(
+            queries, keys, segments=segments, window=800)
+        at = np.searchsorted(keys, queries, side="left")
+        assert idx.shape == found.shape == (q,)
+        np.testing.assert_array_equal(idx, at)
+        np.testing.assert_array_equal(
+            found, (at < n) & (keys[np.minimum(at, n - 1)] == queries))
+        assert unresolved <= max(1, 0.01 * q)
+        assert copies.dispatches == 1
+        # the padded result comes back once: a power-of-two number of
+        # 256-query chunks of i32 indices and 1-byte flags
+        chunks = 1 << (-(-q // 256) - 1).bit_length()
+        assert copies.d2h_bytes == chunks * 256 * (4 + 1)
+
+    def test_chunks_past_the_real_queries_are_not_searched(self):
+        """Of 1024 query slots only the first 300 are real: the chunks
+        that hold none of them come back unresolved, with no window read,
+        and the chunks before them as a call of the real queries alone."""
+        rng = np.random.default_rng(11)
+        keys = np.unique(rng.integers(0, 2**32, 6000, dtype=np.uint32))
+        queries = jnp.asarray(rng.choice(keys, 1024))
+        kj = jnp.asarray(keys)
+        idx, found, iters = (np.asarray(a) for a in optimistic_lookup(
+            queries, kj, n_queries=300, window=512))
+        assert (idx[512:] == -1).all() and not found[512:].any()
+        assert (iters[512:] == 0).all() and (iters[:512] >= 1).all()
+        whole = (np.asarray(a) for a in optimistic_lookup(
+            queries[:512], kj, window=512))
+        for got, want in zip((idx, found, iters), whole):
+            np.testing.assert_array_equal(got[:512], want)
+
 
 class TestBloomCheck:
     @pytest.mark.parametrize("nwords,nadd,k", [(64, 20, 7), (256, 100, 7),
